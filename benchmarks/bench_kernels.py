@@ -8,13 +8,13 @@ and (b) the op-level call graph for the roofline discussion.  ``derived``
 Beyond the raw kernels, the ``backend/*`` rows time the *composed*
 per-part steps (full local-coloring fixed point + conflict sweep) through
 the ``LocalBackend`` interface — the unit the distributed loop actually
-dispatches per round — for reference, pallas, and the ``pallas_fused``
-megakernel; the ``roofline/*`` rows compare the *lowered one-round
-programs* of the chained and fused pallas paths by summing HBM traffic
-over the optimized HLO (``repro.roofline.analysis.hlo_totals``), and the
-run fails if the fused round is not strictly cheaper — the megakernel's
-byte win is measured, not asserted.  ``toy=True`` (the CI
-``kernels_smoke`` suite) shrinks the graph but keeps every row.
+dispatches per round — for reference, pallas, and ``pallas_fused``; the
+``roofline/*`` rows report the *lowered one-round programs* of the chained
+and fused pallas paths as HBM bytes summed over the optimized HLO
+(``repro.roofline.analysis.hlo_totals``).  Those are counts of the CPU
+compiler's program (the kernels in interpret mode), not a chip
+measurement.  ``toy=True`` (the CI ``kernels_smoke`` suite) shrinks the
+graph but keeps every row.
 """
 from __future__ import annotations
 
@@ -84,7 +84,7 @@ def run(toy: bool = False) -> list[str]:
     rows.append(row("kernel/pair_scatter/pallas_interp", us_k, f"match_ref={ok}"))
     rows.append(row("kernel/pair_scatter/jnp_ref", us_r, "oracle"))
 
-    # Fused round megakernel vs the decomposed oracle (d1 boundary/state).
+    # Fused round vs the decomposed oracle (d1 boundary/state).
     bnd1 = jnp.asarray(pg.is_boundary[0])
     colors0 = tab[:nl]
     ghost0 = tab[nl:nl + pg.n_ghost]
@@ -131,12 +131,10 @@ def run(toy: bool = False) -> list[str]:
              for name in ("pallas", "pallas_fused") for i in range(4))
     rows.append(row("backend/parity/round_d1", 0, f"identical={ok}"))
 
-    # Roofline: HBM bytes of the *lowered* one-round programs.  Both
-    # programs are jitted over the same closed-over part-0 state, lowered,
-    # compiled, and their optimized HLO summed by hlo_totals — while-loop
-    # bodies scaled by their trip-count bound.  The chained path pays the
-    # serialized per-edge ghost-lose scatter and re-reads the color table
-    # per sub-program; the megakernel's ballot-style sweep avoids both.
+    # HBM bytes of the *lowered* one-round programs: both are jitted over
+    # the same closed-over part-0 state, lowered, compiled, and their
+    # optimized HLO summed by hlo_totals — while-loop bodies scaled by
+    # their trip-count bound.
     hbytes = {}
     for name in ("pallas", "pallas_fused"):
         b = get_backend(name)
@@ -148,10 +146,6 @@ def run(toy: bool = False) -> list[str]:
         hbytes[name] = int(hlo_totals(text)["hlo_bytes_per_dev"])
         rows.append(row(f"roofline/round_d1/{name}", 0,
                         f"hlo_bytes_per_round={hbytes[name]}"))
-    if hbytes["pallas_fused"] >= hbytes["pallas"]:
-        raise RuntimeError(
-            "fused round must be strictly cheaper than the chained path: "
-            f"fused={hbytes['pallas_fused']} chained={hbytes['pallas']}")
     rows.append(row(
         "roofline/round_d1/fused_vs_chained", 0,
         f"fused={hbytes['pallas_fused']} chained={hbytes['pallas']} "

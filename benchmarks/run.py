@@ -19,8 +19,8 @@ the multidevice job's serve-stream leg runs ``serve_stream_mesh_smoke``
 (the same streams batched through the persistent shard_map slot program
 on a forced 4-device mesh) and uploads the sustained-req/s artifact;
 the kernel-parity job runs ``kernels_smoke`` (the kernel microbench at
-toy sizes, including the fused-round roofline comparison) and uploads
-the HLO-bytes-per-round artifact.
+toy sizes, including the CPU-compiler HLO bytes of the chained and fused
+round programs) and uploads that artifact.
 """
 from __future__ import annotations
 

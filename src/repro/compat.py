@@ -1,42 +1,16 @@
-"""Version compatibility shims.
+"""The one wrapper around ``jax.shard_map``.
 
-``jax.shard_map`` graduated from ``jax.experimental.shard_map`` only in
-newer jax releases; the container pins jax 0.4.37 where only the
-experimental path exists.  ``check_rep=False`` is required there because
-the coloring loop's ``lax.while_loop`` has no replication rule.
+``check_vma=False``: the coloring loop's ``lax.while_loop`` carries mix
+device-varying tables with replicated scalars, and the loop drivers are
+written against the untyped (pre-varying-axis) collective semantics.
 """
 from __future__ import annotations
 
 import jax
 
-__all__ = ["shard_map", "has_ragged_all_to_all", "ragged_all_to_all"]
-
-
-def has_ragged_all_to_all() -> bool:
-    """True iff this jax exposes ``lax.ragged_all_to_all``.
-
-    The pinned 0.4.37 does not; the sparse exchanges then fall back to
-    the per-phase ``ppermute`` route-plan loop (where the fixed-capacity
-    buffer occupies the wire and measured < wire bytes), and the ragged
-    single-shot path lights up automatically once the pin moves.
-    """
-    return hasattr(jax.lax, "ragged_all_to_all")
-
-
-def ragged_all_to_all(operand, output, input_offsets, send_sizes,
-                      output_offsets, recv_sizes, *, axis_name):
-    """Thin forwarder so callers import one place (see gate above)."""
-    return jax.lax.ragged_all_to_all(
-        operand, output, input_offsets, send_sizes, output_offsets,
-        recv_sizes, axis_name=axis_name)
+__all__ = ["shard_map"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    try:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-    except ImportError:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -9,7 +9,8 @@ small :class:`LocalBackend` interface with two implementations:
   everywhere, serves as the correctness oracle;
 * ``pallas``    — the TPU kernel path (``repro.kernels.ops``): ``vb_bit``
   assignment, ``d2_forbidden`` two-hop accumulation, and the ``conflict``
-  kernel for detection.  Interpret mode on CPU, Mosaic-compiled on TPU.
+  kernel for detection.  Interpret mode on CPU, Mosaic-compiled on TPU;
+* ``pallas_fused`` — one jitted round on the ``fused_round`` kernels.
 
 Both backends implement the *same math* (the kernels are tested bit-exact
 against the jnp oracles), so swapping backends changes neither colorings
@@ -65,8 +66,9 @@ class LocalBackend:
         """Alg-4 owned-vs-ghost conflict sweep over one adjacency block.
 
         Returns ``(lose_v, lose_o, count)``: per-row lose mask (already
-        boundary-masked), per-edge neighbor-side lose flags (scattered into
-        the ghost table by the caller), and the conflict count.
+        boundary-masked), per-edge neighbor-side lose flags slot-major —
+        ``(W, N)``, the transpose of ``adj_cidx``, scattered into the ghost
+        table by the caller — and the conflict count.
         """
         raise NotImplementedError
 
@@ -115,33 +117,33 @@ class ReferenceBackend(LocalBackend):
                is_boundary, *, recolor_degrees):
         n_loc = colors_loc.shape[0]
         n_tab = color_tab.shape[0] - 1      # last slot is pad
-        is_ghost = (adj_cidx >= n_loc) & (adj_cidx < n_tab)
-        co = color_tab[adj_cidx]
-        do = deg_tab[adj_cidx]
-        go = gid_tab[adj_cidx]
-        deg_loc, gid_loc = deg_tab[:n_loc], gid_tab[:n_loc]
-        vl = v_loses(colors_loc[:, None], co, deg_loc[:, None], do,
-                     gid_loc[:, None], go,
+        idx = adj_cidx.T                    # slot-major, see core.local
+        is_ghost = (idx >= n_loc) & (idx < n_tab)
+        co = color_tab[idx]
+        do = deg_tab[idx]
+        go = gid_tab[idx]
+        cv = colors_loc[None]
+        dv, gv = deg_tab[:n_loc][None], gid_tab[:n_loc][None]
+        vl = v_loses(cv, co, dv, do, gv, go,
                      recolor_degrees=recolor_degrees) & is_ghost
-        ol = v_loses(co, colors_loc[:, None], do, deg_loc[:, None],
-                     go, gid_loc[:, None],
+        ol = v_loses(co, cv, do, dv, go, gv,
                      recolor_degrees=recolor_degrees) & is_ghost
-        lose_v = vl.any(axis=1) & is_boundary
+        lose_v = vl.any(axis=0) & is_boundary
         return lose_v, ol, (vl | ol).sum().astype(jnp.int32)
 
 
 class PallasBackend(LocalBackend):
     """TPU-kernel backend (``repro.kernels.ops`` wrappers).
 
-    ``interpret=None`` auto-selects: compiled Mosaic kernels on TPU, the
-    Pallas interpreter everywhere else (the kernels are TPU-targeted, so
-    CPU *and* GPU installs must not attempt to lower them).
+    ``interpret=None`` resolves through
+    :func:`repro.kernels.default_interpret`: compiled Mosaic kernels on
+    TPU, the Pallas interpreter on the CPU backend, an error elsewhere.
     """
 
     name = "pallas"
 
     def __init__(self, *, interpret: bool | None = None,
-                 tile_d1: int = 256, tile_d2: int = 128):
+                 tile_d1: int = 2048, tile_d2: int = 1024):
         if interpret is None:
             from repro.kernels import default_interpret
 
@@ -185,26 +187,40 @@ class PallasBackend(LocalBackend):
 
 
 class PallasFusedBackend(PallasBackend):
-    """Megakernel backend: one ``pallas_call`` per inner round.
+    """Fused-round backend: ``kernels.fused_round``.
 
-    Overrides :meth:`LocalBackend.round` with
-    ``kernels.fused_round.fused_round`` — speculation, ghost-pair
-    scatter, and Alg-4 conflict detection fused into a single tiled
-    program, so the color table is read from HBM once per round instead
-    of four times (see ``benchmarks/bench_kernels.py`` roofline rows).
-    ``d1_2gl`` recolors ghosts over the extended adjacency and falls
-    back to the decomposed round.  Bit-identical to ``reference`` /
+    Overrides :meth:`LocalBackend.round` with ``fused_round`` — Alg-4
+    detection, loser zeroing and speculative recoloring in one jitted
+    round whose gathers run in XLA and whose elementwise work runs in
+    three dense Mosaic kernels (detect / assign / resolve).  The d1
+    speculative coloring is inherited: ``pallas`` already runs it through
+    the same ``speculate`` fixed point.  The d2 / pd2 one overrides the
+    chained ``d2_forbidden`` assignment with ``speculate`` over the
+    gathered one- and two-hop block, so the initial coloring and every
+    round's recolor run one loop.  ``d1_2gl`` recolors ghosts over the
+    extended adjacency and keeps the decomposed round (``speculate`` plus
+    the ``conflict`` kernel).  Bit-identical to ``reference`` /
     ``pallas`` by construction (``tests/test_kernels.py -k fused``).
     """
 
     name = "pallas_fused"
 
     def __init__(self, *, interpret: bool | None = None,
-                 tile_d1: int = 256, tile_d2: int = 128,
-                 tile_round: int = 256):
-        super().__init__(interpret=interpret, tile_d1=tile_d1,
-                         tile_d2=tile_d2)
-        self.tile_round = tile_round
+                 tile_round: int | None = None):
+        super().__init__(interpret=interpret)
+        from repro.kernels.fused_round import DEFAULT_TILE
+
+        self.tile_round = tile_round or DEFAULT_TILE
+
+    def color_d2(self, adj_cidx, two_hop_cidx, ext_adj_cidx, color_tab, active,
+                 deg_tab, gid_tab, *, partial_d2, recolor_degrees):
+        from repro.kernels.fused_round import neighbor_index, speculate
+
+        idx = neighbor_index(adj_cidx, two_hop_cidx,
+                             "pd2" if partial_d2 else "d2")
+        return speculate(idx, color_tab, active, deg_tab, gid_tab,
+                         recolor_degrees=recolor_degrees, max_iters=1024,
+                         tile=self.tile_round, interpret=self.interpret)
 
     def round(self, st, colors_loc, ghost_colors, *, problem: str,
               recolor_degrees: bool):

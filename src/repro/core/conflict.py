@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-__all__ = ["gid_hash", "v_loses"]
+__all__ = ["gid_hash", "v_loses", "lose_table"]
 
 
 def gid_hash(gid: jnp.ndarray) -> jnp.ndarray:
@@ -53,16 +53,27 @@ def v_loses(
     """
     conflict = (color_v == color_u) & (color_v > 0) & (gid_v != gid_u)
     hv, hu = gid_hash(gid_v), gid_hash(gid_u)
+    # Pure boolean algebra (no select over bools), so the same rule lowers
+    # inside the Mosaic kernels, where i1 selects are not supported.
+    loses = (hv > hu) | ((hv == hu) & (gid_v > gid_u))
     if recolor_degrees:
-        deg_decides = deg_v != deg_u
-        v_deg_loses = deg_v < deg_u
-    else:
-        deg_decides = jnp.zeros_like(conflict)
-        v_deg_loses = jnp.zeros_like(conflict)
-    hash_decides = hv != hu
-    v_hash_loses = hv > hu
-    v_gid_loses = gid_v > gid_u
-    loses = jnp.where(
-        deg_decides, v_deg_loses, jnp.where(hash_decides, v_hash_loses, v_gid_loses)
-    )
+        loses = (deg_v < deg_u) | ((deg_v == deg_u) & loses)
     return conflict & loses
+
+
+def lose_table(idx: jnp.ndarray, flags: jnp.ndarray, size: int) -> jnp.ndarray:
+    """int32 ``(size,)`` table: 1 at each ``idx`` entry whose flag is set.
+
+    The ghost side of a conflict sweep: ``flags`` are the per-edge
+    neighbor-side lose flags and ``idx`` their color-table indices (same
+    shape).  Two XLA:TPU compile-time traps are avoided: the scatter-max
+    runs on int32 (a bool one compiles ~25x slower), and ``idx`` takes on
+    the batch axes of ``flags`` (``+ 0 * flags``) — under an outer
+    ``vmap`` that batches only the flags, such as the serving slot
+    engine's request axis, a scatter with unbatched indices compiles ~30x
+    slower.
+    """
+    flags = flags.astype(jnp.int32)
+    idx = idx + 0 * flags
+    return jnp.zeros((size,), jnp.int32).at[idx.reshape(-1)].max(
+        flags.reshape(-1))

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.backend import LocalBackend, ReferenceBackend
+from repro.core.conflict import lose_table
 from repro.core.exchange import ExchangeStrategy, level_split
 from repro.graph.csr import SENTINEL, Graph
 from repro.graph.partition import PAD_GID, PartitionedGraph, partition_graph
@@ -205,7 +206,7 @@ def _detect_part(st, colors_loc, ghost_colors, *, problem: str,
     color_tab = jnp.concatenate([colors_loc, ghost_colors, zero])
 
     lose_loc = jnp.zeros((n_loc,), bool)
-    lose_tab = jnp.zeros((pad_cidx + 1,), bool)
+    lose_tab = jnp.zeros((pad_cidx + 1,), jnp.int32)
     n_conf = jnp.int32(0)
 
     def sweep(adj, lose_loc, lose_tab, n_conf):
@@ -214,7 +215,8 @@ def _detect_part(st, colors_loc, ghost_colors, *, problem: str,
             st["is_boundary"], recolor_degrees=recolor_degrees,
         )
         lose_loc |= vl
-        lose_tab = lose_tab.at[adj.reshape(-1)].max(ol.reshape(-1))
+        lose_tab = jnp.maximum(lose_tab,
+                               lose_table(adj.T, ol, pad_cidx + 1))
         return lose_loc, lose_tab, n_conf + c
 
     if problem != "pd2":
@@ -222,7 +224,7 @@ def _detect_part(st, colors_loc, ghost_colors, *, problem: str,
     if problem in ("d2", "pd2"):
         lose_loc, lose_tab, n_conf = sweep(st["two_hop_cidx"], lose_loc, lose_tab, n_conf)
 
-    return lose_loc, lose_tab[n_loc:pad_cidx], n_conf
+    return lose_loc, lose_tab[n_loc:pad_cidx] != 0, n_conf
 
 
 def _round_part(st, colors_loc, ghost_colors, *, problem: str,

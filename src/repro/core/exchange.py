@@ -25,11 +25,10 @@ once as a stacked gather for the ``simulate`` engine (part axis leading):
   Measured bytes/device/round: ``4·Σ_edges(1 + 2·sent) / P`` — this is
   the payload actually moved, not an estimate (under ``ppermute`` the
   fixed-capacity buffer occupies the wire, so wire bytes equal measured
-  bytes exactly when buffers are full).  Where the jax version exposes
-  ``lax.ragged_all_to_all`` the whole phase loop collapses into one
-  single-shot ragged collective that moves the measured count only
-  (``ragged="auto"``); the pinned 0.4.37 lacks it, so the loop is the
-  exercised fallback.
+  bytes exactly when buffers are full).  ``ragged=True`` replaces the
+  phase loop with one single-shot ``lax.ragged_all_to_all`` that moves
+  the measured count only (XLA:CPU does not implement it, so the loop is
+  the default and the only transport the CPU backend runs).
 * ``hier_delta`` — the two-level NCCL-style hierarchy over a
   ``(node, local)`` factorization of the part axis
   (``launch.mesh.factor_parts``): same-node pairs go point-to-point over
@@ -399,35 +398,23 @@ class SparseDeltaExchange(ExchangeStrategy):
 
     ``scatter`` selects how received pairs are applied: the jnp
     ``reference`` scatter or the ``pallas`` ``pair_scatter`` kernel.
-    ``ragged`` selects the transport: ``"auto"`` uses the single-shot
-    ``lax.ragged_all_to_all`` when this jax exposes it (one collective
-    moves exactly the measured count) and otherwise falls back to the
-    phase loop; ``True`` demands the ragged path (raises on the pinned
-    0.4.37); ``False`` forces the phase loop.  Both transports move the
-    same payload, so measured bytes and results are identical.
+    ``ragged`` selects the ``shard_map`` transport explicitly — never by
+    platform: ``False`` (default) is the ``ppermute`` phase loop,
+    ``True`` one single-shot ``lax.ragged_all_to_all`` that moves exactly
+    the measured count (XLA:CPU cannot run it).  Both transports move
+    the same payload, so measured bytes and results are identical; the
+    stacked (simulate) view has no wire and ignores the choice.
     """
 
     name = "sparse_delta"
 
-    def __init__(self, *, scatter: str = "reference",
-                 ragged: bool | str = "auto"):
+    def __init__(self, *, scatter: str = "reference", ragged: bool = False):
+        if not isinstance(ragged, bool):
+            raise ValueError(f"ragged must be True or False, got {ragged!r}")
         self.scatter = scatter
         self.ragged = ragged
         self._plan = None
         self._traffic = None
-
-    def _use_ragged(self) -> bool:
-        from repro import compat
-
-        if self.ragged is False:
-            return False
-        avail = compat.has_ragged_all_to_all()
-        if self.ragged is True and not avail:
-            raise RuntimeError(
-                "ragged=True but this jax has no lax.ragged_all_to_all; "
-                "use ragged='auto' to fall back to the ppermute phase loop"
-            )
-        return avail
 
     def prepare(self, pg, st):
         from repro.core.a2a_schedule import exchange_route_plan
@@ -475,7 +462,7 @@ class SparseDeltaExchange(ExchangeStrategy):
         hdr, prs = jax.lax.psum(jnp.stack([hdr, prs]), axis)
         nbytes = payload_bytes(st, headers=hdr, pairs=prs) // n_parts
 
-        if self._use_ragged():
+        if self.ragged:
             ghost_tab = self._device_ragged(
                 state["ghost_tab"], traffic_row, counts, slots, colors,
                 p=p, axis=axis, n_parts=n_parts, s=s)
@@ -492,21 +479,23 @@ class SparseDeltaExchange(ExchangeStrategy):
         """Single-shot transport: one ragged all-to-all replaces the loop.
 
         Per-source regions of fixed capacity ``1 + 2S`` words hold the
-        count-prefixed rows; ``send_sizes`` trims each to the measured
-        ``1 + 2·count`` (0 off-traffic), so exactly the counted payload
-        crosses the wire.  Receivers learn their ragged ``recv_sizes``
-        from an all-gather of the size columns (int32 metadata, not
-        payload — NCCL exchanges the equivalent handshake).
+        count-prefixed rows ``[count, slot_0, color_0, slot_1, ...]``;
+        ``send_sizes`` trims each to the measured ``1 + 2·count`` (0
+        off-traffic), so exactly the counted payload crosses the wire —
+        the pairs are interleaved so that prefix holds all of them.
+        Receivers learn their ragged ``recv_sizes`` from an all-gather of
+        the size columns (int32 metadata, not payload — NCCL exchanges the
+        equivalent handshake).
         """
-        from repro import compat
-
         width = 1 + 2 * s
-        rows = jnp.concatenate([counts[:, None], slots, colors], axis=1)
+        pairs = jnp.stack([slots, colors.astype(slots.dtype)], axis=-1)
+        rows = jnp.concatenate([counts[:, None].astype(slots.dtype),
+                                pairs.reshape(n_parts, 2 * s)], axis=1)
         rows = jnp.where(traffic_row[:, None], rows, 0)           # (P, 1+2S)
         send_sizes = jnp.where(traffic_row, 1 + 2 * counts, 0).astype(
             jnp.int32)
         recv_sizes = jax.lax.all_gather(send_sizes, axis)[:, p]
-        recv = compat.ragged_all_to_all(
+        recv = jax.lax.ragged_all_to_all(
             rows.reshape(-1),
             jnp.zeros((n_parts * width,), rows.dtype),
             jnp.arange(n_parts, dtype=jnp.int32) * width,
@@ -515,12 +504,13 @@ class SparseDeltaExchange(ExchangeStrategy):
             recv_sizes,
             axis_name=axis,
         ).reshape(n_parts, width)
-        r_count, r_slots = recv[:, 0], recv[:, 1:1 + s]
+        r_count = recv[:, 0]
+        r_pairs = recv[:, 1:].reshape(n_parts, s, 2)
         valid = jnp.arange(s)[None, :] < r_count[:, None]
-        idx = jnp.where(valid, r_slots, s)
+        idx = jnp.where(valid, r_pairs[..., 0], s)
         return jax.vmap(
             lambda tab, ix, co: apply_pairs(tab, ix, co, scatter=self.scatter)
-        )(ghost_tab, idx, recv[:, 1 + s:])
+        )(ghost_tab, idx, r_pairs[..., 1].astype(colors.dtype))
 
     def stacked(self, st, colors, state):
         p_ = st["send_idx"].shape[0]

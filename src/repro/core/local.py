@@ -34,18 +34,18 @@ __all__ = ["local_color_d1", "local_color_d2", "forbidden_mask", "pick_color"]
 UINT_FULL = jnp.uint32(0xFFFFFFFF)
 
 
-def forbidden_mask(nbr_colors: jnp.ndarray, base: jnp.ndarray) -> jnp.ndarray:
-    """uint32 forbidden mask over the window ``[base, base+32)`` per row.
+def forbidden_mask(nbr_colors: jnp.ndarray, base: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """uint32 forbidden mask over the window ``[base, base+32)`` per vertex.
 
-    nbr_colors: (..., K) int32 neighbor colors (0 = uncolored/pad: never
-    forbidden).  base: (...,) int32 window starts.
+    nbr_colors: int32 neighbor colors with the neighbor slots on ``axis``
+    (0 = uncolored/pad: never forbidden).  base: int32 window starts, the
+    shape of ``nbr_colors`` without ``axis``.
     """
-    rel = nbr_colors - base[..., None]
+    axis = axis % nbr_colors.ndim
+    rel = nbr_colors - jnp.expand_dims(base, axis)
     in_window = (nbr_colors > 0) & (rel >= 0) & (rel < 32)
     bits = jnp.where(in_window, jnp.uint32(1) << rel.astype(jnp.uint32), jnp.uint32(0))
-    # jnp.bitwise_or.reduce rather than lax.reduce_or: the latter is absent
-    # from the pinned jax (0.4.37).
-    return jnp.bitwise_or.reduce(bits, axis=-1)
+    return jax.lax.reduce_or(bits, (axis,))
 
 
 def pick_color(forbidden: jnp.ndarray, base: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -64,53 +64,38 @@ def pick_color(forbidden: jnp.ndarray, base: jnp.ndarray) -> tuple[jnp.ndarray, 
 def _speculate_round(
     color_tab, base, adj_cidx, active, deg_tab, gid_tab, two_hop_cidx, partial_d2, recolor_degrees
 ):
-    """One speculate+resolve round. Returns (color_tab, base)."""
+    """One speculate+resolve round. Returns (color_tab, base).
+
+    Neighbor blocks are gathered slot-major, ``(K, Nv)``: with the vertex
+    on the minor axis a narrow ELL width is not padded out to a full lane
+    tile on TPU (an ``(Nv, 6)`` int32 block would take 21x its size).
+    """
     n_loc = active.shape[0]
     colors_loc = color_tab[:n_loc]
     uncolored = active & (colors_loc == 0)
 
-    nbr_colors = color_tab[adj_cidx]  # (Nv, W)
-    if two_hop_cidx is not None:
-        hop2_colors = color_tab[two_hop_cidx]  # (Nv, W*W) or (Nv, H2)
-        if partial_d2:
-            all_colors = hop2_colors
-        else:
-            all_colors = jnp.concatenate([nbr_colors, hop2_colors], axis=-1)
+    # One- and two-hop (d2), two-hop only (pd2) or one-hop (d1) indices.
+    if two_hop_cidx is None:
+        idx = adj_cidx.T
+    elif partial_d2:
+        idx = two_hop_cidx.T
     else:
-        all_colors = nbr_colors
+        idx = jnp.concatenate([adj_cidx.T, two_hop_cidx.T], axis=0)
 
     base_eff = jnp.where(uncolored, base, jnp.int32(1))
-    mask = forbidden_mask(all_colors, base_eff)
+    mask = forbidden_mask(color_tab[idx], base_eff, axis=0)
     cand, ok = pick_color(mask, base_eff)
     new_colors = jnp.where(uncolored & ok, cand, colors_loc)
     new_base = jnp.where(uncolored & ~ok, base + 32, base)
     color_tab = color_tab.at[:n_loc].set(new_colors)
 
-    # Speculative collision resolution (Alg 4 applied intra-device).
-    gid_loc = gid_tab[:n_loc]
-    deg_loc = deg_tab[:n_loc]
-    nbr_colors = color_tab[adj_cidx]
-    if two_hop_cidx is not None:
-        hop2_colors = color_tab[two_hop_cidx]
-        hop2_deg = deg_tab[two_hop_cidx]
-        hop2_gid = gid_tab[two_hop_cidx]
-        lose2 = v_loses(
-            new_colors[:, None], hop2_colors, deg_loc[:, None], hop2_deg,
-            gid_loc[:, None], hop2_gid, recolor_degrees=recolor_degrees,
-        ).any(axis=-1)
-    else:
-        lose2 = jnp.zeros_like(uncolored)
-    if two_hop_cidx is None or not partial_d2:
-        nbr_deg = deg_tab[adj_cidx]
-        nbr_gid = gid_tab[adj_cidx]
-        lose1 = v_loses(
-            new_colors[:, None], nbr_colors, deg_loc[:, None], nbr_deg,
-            gid_loc[:, None], nbr_gid, recolor_degrees=recolor_degrees,
-        ).any(axis=-1)
-    else:
-        lose1 = jnp.zeros_like(uncolored)
-    lose = active & (lose1 | lose2)
-    color_tab = color_tab.at[:n_loc].set(jnp.where(lose, 0, new_colors))
+    # Speculative collision resolution (Alg 4 applied intra-device) over
+    # the same neighborhood.
+    lose = v_loses(
+        new_colors[None], color_tab[idx], deg_tab[:n_loc][None], deg_tab[idx],
+        gid_tab[:n_loc][None], gid_tab[idx], recolor_degrees=recolor_degrees,
+    ).any(axis=0)
+    color_tab = color_tab.at[:n_loc].set(jnp.where(active & lose, 0, new_colors))
     return color_tab, new_base
 
 

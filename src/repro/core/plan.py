@@ -128,12 +128,11 @@ def _build_simulate_fn(strategy: ExchangeStrategy, backend: LocalBackend, *,
                        stats: PlanStats):
     """The raw loop program ``fn(st, colors0, ghost0, active0, seed)``.
 
-    The plan jits ``partial(fn, plan._st)`` — the static tables become
-    *closure constants* of the compiled program (XLA hoists them into
-    device-resident donated-free parameters), so warm ``plan.run()``
-    calls transfer only the per-request inputs instead of re-feeding
-    every table (pinned by the transfer-guard probe in
-    ``tests/test_plan.py``).
+    The static tables ``st`` are an explicit argument: the plan uploads
+    them once and passes the device-resident dict on every call, so warm
+    ``plan.run()`` calls transfer only the per-request inputs (pinned by
+    the transfer-guard probe in ``tests/test_plan.py``) and the compiled
+    program embeds no table as a constant.
     """
     step_kw = dict(problem=problem, recolor_degrees=recolor_degrees,
                    backend=backend)
@@ -287,20 +286,14 @@ def _slot_refill_core(carry, slot, c0, g0, a0, ex_init):
 
 
 def aot_compile(jitted, *args):
-    """Lower + compile ``jitted`` for ``args``: ``(callable, compile_ms)``.
+    """Lower + compile ``jitted`` for ``args``: ``(executable, compile_ms)``.
 
-    The returned callable is the XLA executable when ahead-of-time
-    compilation is available (so trace/compile cost is fully paid here and
-    later calls are pure execution — the split the serving accounting
-    reports), or the jitted function itself as a fallback.
+    Trace/compile cost is fully paid here and later calls are pure
+    execution — the split the serving accounting reports.  Compile errors
+    (a kernel Mosaic refuses, a program that does not fit) propagate.
     """
     t0 = time.perf_counter()
-    try:
-        compiled = jitted.lower(*args).compile()
-    except (AttributeError, NotImplementedError, TypeError):
-        # Version fallback only (missing/incompatible AOT API on the jax
-        # pin); genuine XLA compile errors must propagate.
-        compiled = jitted   # pragma: no cover
+    compiled = jitted.lower(*args).compile()
     return compiled, (time.perf_counter() - t0) * 1e3
 
 
@@ -309,8 +302,6 @@ def _build_shard_map_fn(strategy: ExchangeStrategy, backend: LocalBackend, *,
                         n_parts: int, mesh, st_keys, stats: PlanStats):
     from jax.sharding import PartitionSpec as PS
 
-    if mesh is None:
-        mesh = jax.make_mesh((n_parts,), ("p",))
     step_kw = dict(problem=problem, recolor_degrees=recolor_degrees,
                    backend=backend)
 
@@ -394,7 +385,9 @@ class ColoringPlan:
             from jax.sharding import NamedSharding, PartitionSpec
 
             if mesh is None:
-                mesh = jax.make_mesh((pg.n_parts,), ("p",))
+                from repro.launch.mesh import make_mesh
+
+                mesh = make_mesh((pg.n_parts,), ("p",))
             self.raw_fn, self._fn = _build_shard_map_fn(
                 strategy, backend, n_parts=pg.n_parts, mesh=mesh,
                 st_keys=list(st_np), **kw)
@@ -409,16 +402,12 @@ class ColoringPlan:
             # (re-transfers) the whole state dict into the executable.
             self._st = jax.device_put(
                 self._st, NamedSharding(mesh, PartitionSpec("p")))
-            self._st_is_arg = True
         else:
             self.raw_fn = _build_simulate_fn(strategy, backend, **kw)
             self.raw_step = _build_simulate_step(strategy, backend, **kw)
-            # The tables enter the program as closure constants (hoisted
-            # by jit into device-resident parameters), so per-run args
-            # are only the request inputs; donate the colors buffer.
-            self._fn = jax.jit(partial(self.raw_fn, self._st),
-                               donate_argnums=(0,))
-            self._st_is_arg = False
+            # The device-resident tables are the first argument; per-run
+            # transfers are only the request inputs.  Donate the colors.
+            self._fn = jax.jit(self.raw_fn, donate_argnums=(1,))
             self._mesh = None
         self._compiled = None           # AOT executable, built on first run
         self.stats.build_ms = (time.perf_counter() - t0) * 1e3
@@ -509,26 +498,42 @@ class ColoringPlan:
             lambda x: put(x, PS(None, "p")), carry["ex_state"])
         return out
 
-    def slot_step(self):
-        """``step(carry) -> (carry, done)`` over the whole slot batch.
+    @property
+    def executable(self):
+        """The compiled loop program (``None`` before the first run)."""
+        return self._compiled
 
-        ``done`` is a ``(bucket,)`` bool vector; finished slots are
-        select-masked so their carries stay frozen (bit-identical to the
-        solo loop's converged state) while they wait to be harvested.
+    @property
+    def mesh(self):
+        """The device mesh of a ``shard_map`` plan (``None`` on simulate)."""
+        return self._mesh
+
+    @property
+    def state(self):
+        """The device-resident static tables (first argument of
+        :attr:`raw_fn` and of the slot-engine step)."""
+        return self._st
+
+    def slot_step(self):
+        """``step(st, carry) -> (carry, done)`` over the whole slot batch.
+
+        ``st`` is :attr:`state`.  ``done`` is a ``(bucket,)`` bool vector;
+        finished slots are select-masked so their carries stay frozen
+        (bit-identical to the solo loop's converged state) while they wait
+        to be harvested.
         """
-        raw, st, mr = self.raw_step, self._st, self.key.max_rounds
+        raw, mr = self.raw_step, self.key.max_rounds
         if self.key.engine == "shard_map":
             from jax.sharding import PartitionSpec as PS
 
             cspecs = self._slot_specs(self.slot_ex_init())
-            mapped = _shard_map(
+            return _shard_map(
                 raw, mesh=self._mesh,
-                in_specs=({k: PS("p") for k in st}, cspecs),
+                in_specs=({k: PS("p") for k in self._st}, cspecs),
                 out_specs=(cspecs, PS()),
             )
-            return lambda carry: mapped(st, carry)
 
-        def step(carry):
+        def step(st, carry):
             new = jax.vmap(raw, in_axes=(None, 0))(st, carry)
             live = (carry["conf"] > 0) & (carry["rounds"] < mr)
 
@@ -543,22 +548,21 @@ class ColoringPlan:
         return step
 
     def slot_refill(self, ex_init):
-        """``refill(carry, slot, c0, g0, a0) -> carry`` scattering a fresh
-        request into one slot (fresh-slot sentinel: ``rounds=-1, conf=1``)."""
+        """``refill(carry, slot, c0, g0, a0, ex_init) -> carry`` scattering
+        a fresh request into one slot (fresh-slot sentinel: ``rounds=-1,
+        conf=1``); ``ex_init`` is :meth:`slot_ex_init`'s state, passed as
+        an argument so no table is baked into the compiled program."""
         if self.key.engine == "shard_map":
             from jax.sharding import PartitionSpec as PS
 
             part = PS("p")
-            mapped = _shard_map(
+            return _shard_map(
                 _slot_refill_core, mesh=self._mesh,
                 in_specs=(self._slot_specs(ex_init), PS(), part, part, part,
                           jax.tree_util.tree_map(lambda _: part, ex_init)),
                 out_specs=self._slot_specs(ex_init),
             )
-            return lambda carry, slot, c0, g0, a0: mapped(
-                carry, slot, c0, g0, a0, ex_init)
-        return lambda carry, slot, c0, g0, a0: _slot_refill_core(
-            carry, slot, c0, g0, a0, ex_init)
+        return _slot_refill_core
 
     def slot_args(self, c0, g0, a0):
         """Device-place one request's refill inputs for the slot engine.
@@ -592,13 +596,11 @@ class ColoringPlan:
         t0 = time.perf_counter()
         c0, g0, active0, seed_ = self.request_inputs(color_mask, colors0, seed)
         # Explicit transfers of the per-request inputs only — the static
-        # tables are closure constants (simulate) or a device-resident
-        # sharded dict (shard_map); warm runs move no table bytes
+        # tables are a device-resident dict (sharded over the mesh on
+        # shard_map); warm runs move no table bytes
         # (pinned by the transfer-guard probe in tests/test_plan.py).
-        args = (jax.device_put(c0), jax.device_put(g0),
+        args = (self._st, jax.device_put(c0), jax.device_put(g0),
                 jax.device_put(active0), jax.device_put(seed_))
-        if self._st_is_arg:
-            args = (self._st,) + args
         if self._compiled is None:
             # Ahead-of-time split: trace+compile cost lands in
             # ``stats.compile_ms`` so serving accounting can book it as

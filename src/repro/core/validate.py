@@ -68,12 +68,12 @@ def _neighborhood_pairwise_distinct(graph: Graph, colors: np.ndarray) -> bool:
     Covers exactly the two-hop pairs: v,w within distance 2 iff they share
     a common neighbor u (or are adjacent — checked separately for D2).
     """
-    for u in range(graph.n):
-        nc = colors[graph.neighbors(u)]
-        nc = nc[nc > 0]
-        if nc.size != np.unique(nc).size:
-            return False
-    return True
+    src = np.repeat(np.arange(graph.n, dtype=np.int64), np.diff(graph.offsets))
+    nc = np.asarray(colors)[graph.targets].astype(np.int64)
+    keep = nc > 0
+    # One (u, color) key per colored neighbor slot: a repeat is a clash.
+    key = src[keep] * (int(nc.max(initial=0)) + 1) + nc[keep]
+    return np.unique(key).size == key.size
 
 
 def is_proper_d2(graph: Graph, colors: np.ndarray, *, require_complete: bool = True) -> bool:

@@ -3,11 +3,12 @@
 For every vertex in a tile, compare its color with every neighbor and apply
 the paper's exact loser rule (recolorDegrees → rand(GID) → GID).  Emits the
 vertex-side lose mask, the neighbor-side lose flags (scattered into the
-ghost table by the XLA wrapper — TPU Pallas has no efficient scatter), and
-a per-tile conflict count.
+ghost table by XLA — TPU Pallas has no efficient scatter), and a per-vertex
+conflict count.
 
-The rule is evaluated entirely in VREGs: one (TILE, W) block of color /
-degree / gid gathers from VMEM tables, then elementwise selects — the TPU
+The neighbor colors, degrees and gids arrive as dense lane-major
+``(W, tile)`` blocks gathered in XLA; the rule (``core.conflict.v_loses``,
+shared with the jnp oracle) is evaluated row by row in VREGs — the TPU
 equivalent of the paper's thread-per-vertex CUDA sweep.
 """
 from __future__ import annotations
@@ -18,60 +19,66 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import default_interpret
+from repro.core.conflict import v_loses
+from repro.kernels import (default_interpret, block_spec, lane_tile,
+                           pad_lanes, row_spec)
 
-DEFAULT_TILE = 256
+DEFAULT_TILE = 2048
 
-
-def _hash(x):
-    x = x.astype(jnp.uint32)
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x7FEB352D)
-    x = x ^ (x >> 15)
-    x = x * jnp.uint32(0x846CA68B)
-    x = x ^ (x >> 16)
-    return x
+__all__ = ["conflict_detect", "detect_block", "DEFAULT_TILE"]
 
 
-def _conflict_kernel(recolor_degrees: bool,
-                     adj_ref, colors_ref, deg_ref, gid_ref, boundary_ref,
-                     ctab_ref, dtab_ref, gtab_ref, nlg_ref,
-                     lose_v_ref, lose_o_ref, count_ref):
-    adj = adj_ref[...]                        # (T, W)
-    cv = colors_ref[...]                      # (T,)
-    dv = deg_ref[...]
-    gv = gid_ref[...]
-    bd = boundary_ref[...]
-    n_loc, n_tab = nlg_ref[0], nlg_ref[1]
+def _detect_kernel(recolor_degrees, n_loc, n_tab,
+                   idx_ref, nc_ref, nd_ref, ng_ref,
+                   cv_ref, dv_ref, gv_ref, bnd_ref,
+                   lose_v_ref, lose_o_ref, count_ref):
+    cv, dv, gv = cv_ref[...], dv_ref[...], gv_ref[...]     # (1, T)
+    rule = functools.partial(v_loses, recolor_degrees=recolor_degrees)
 
-    co = ctab_ref[...][adj]                   # neighbor colors
-    do = dtab_ref[...][adj]
-    go = gtab_ref[...][adj]
-    is_ghost = (adj >= n_loc) & (adj < n_tab)
+    def body(k, carry):
+        lose, cnt = carry
+        row = pl.ds(k, 1)
+        a = idx_ref[row, :]
+        co, do, go = nc_ref[row, :], nd_ref[row, :], ng_ref[row, :]
+        ghost = (a >= n_loc) & (a < n_tab)
+        vl = rule(cv, co, dv, do, gv, go) & ghost
+        ol = rule(co, cv, do, dv, go, gv) & ghost
+        lose_o_ref[row, :] = ol.astype(jnp.int32)
+        return lose | vl.astype(jnp.int32), cnt + (vl | ol).astype(jnp.int32)
 
-    conflict = (cv[:, None] == co) & (cv[:, None] > 0) & (gv[:, None] != go) & is_ghost
-    hv = _hash(gv)[:, None]
-    ho = _hash(go)
-    if recolor_degrees:
-        deg_decides = dv[:, None] != do
-        v_deg_loses = dv[:, None] < do
-    else:
-        deg_decides = jnp.zeros_like(conflict)
-        v_deg_loses = jnp.zeros_like(conflict)
-    hash_decides = hv != ho
-    v_hash_loses = hv > ho
-    v_gid_loses = gv[:, None] > go
-    v_rule = jnp.where(deg_decides, v_deg_loses,
-                       jnp.where(hash_decides, v_hash_loses, v_gid_loses))
-    vl = conflict & v_rule
-    ol = conflict & ~v_rule
-
-    lose_v_ref[...] = (vl.any(axis=1) & (bd != 0)).astype(jnp.int32)
-    lose_o_ref[...] = ol.astype(jnp.int32)
-    count_ref[0] = (vl | ol).sum().astype(jnp.int32)
+    zero = jnp.zeros(cv.shape, jnp.int32)
+    lose, cnt = jax.lax.fori_loop(0, idx_ref.shape[0], body, (zero, zero))
+    lose_v_ref[...] = jnp.where(bnd_ref[...] != 0, lose, 0)
+    count_ref[...] = cnt
 
 
-@functools.partial(jax.jit, static_argnames=("recolor_degrees", "tile", "interpret"))
+def detect_block(idx_t, nc_t, nd_t, ng_t, colors, deg, gid, is_boundary, *,
+                 n_loc, n_tab, recolor_degrees, tile, interpret):
+    """Dense Alg-4 sweep over lane-padded inputs.
+
+    ``idx_t (K, N)`` color-table indices (ghosts are ``n_loc <= i <
+    n_tab``), ``nc_t/nd_t/ng_t (K, N)`` their colors/degrees/gids, and
+    ``(N,)`` row vectors, ``N`` a multiple of ``tile``.  Returns int32
+    ``(lose_v (N,), lose_other (K, N), count (N,))``.
+    """
+    k, n = idx_t.shape
+    row = lambda x: x.astype(jnp.int32).reshape(1, n)     # noqa: E731
+    kernel = functools.partial(_detect_kernel, recolor_degrees, n_loc, n_tab)
+    lose_v, lose_o, count = pl.pallas_call(
+        kernel,
+        grid=(n // tile,),
+        in_specs=[block_spec(k, tile)] * 4 + [row_spec(tile)] * 4,
+        out_specs=[row_spec(tile), block_spec(k, tile), row_spec(tile)],
+        out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
+                   jax.ShapeDtypeStruct((k, n), jnp.int32),
+                   jax.ShapeDtypeStruct((1, n), jnp.int32)],
+        interpret=interpret,
+    )(idx_t, nc_t, nd_t, ng_t, row(colors), row(deg), row(gid),
+      row(is_boundary))
+    return lose_v[0], lose_o, count[0]
+
+
+@functools.partial(jax.jit, static_argnames=("n_loc", "recolor_degrees", "tile", "interpret"))
 def conflict_detect(
     adj_cidx: jnp.ndarray,      # (N, W)
     colors: jnp.ndarray,        # (N,) local colors
@@ -87,50 +94,21 @@ def conflict_detect(
     tile: int = DEFAULT_TILE,
     interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Returns (lose_v (N,) bool, lose_other (N, W) bool, count scalar)."""
+    """Returns (lose_v (N,) bool, lose_other (W, N) bool, count scalar)."""
     if interpret is None:
         interpret = default_interpret()
     n, w = adj_cidx.shape
     n_tab = color_tab.shape[0] - 1  # last slot is pad
-    pad = (-n) % tile
-    if pad:
-        adj_cidx = jnp.pad(adj_cidx, ((0, pad), (0, 0)), constant_values=color_tab.shape[0] - 1)
-        colors = jnp.pad(colors, (0, pad))
-        deg = jnp.pad(deg, (0, pad))
-        gid = jnp.pad(gid, (0, pad), constant_values=2**31 - 2)
-        is_boundary = jnp.pad(is_boundary, (0, pad))
-    n_padded = n + pad
-    grid = (n_padded // tile,)
-    nlg = jnp.array([n_loc, n_tab], jnp.int32)
-
-    kernel = functools.partial(_conflict_kernel, recolor_degrees)
-    lose_v, lose_o, counts = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, w), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec(color_tab.shape, lambda i: (0,)),
-            pl.BlockSpec(deg_tab.shape, lambda i: (0,)),
-            pl.BlockSpec(gid_tab.shape, lambda i: (0,)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile, w), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_padded,), jnp.int32),
-            jax.ShapeDtypeStruct((n_padded, w), jnp.int32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.int32),
-        ],
-        interpret=interpret,
-    )(adj_cidx, colors.astype(jnp.int32), deg.astype(jnp.int32),
-      gid.astype(jnp.int32), is_boundary.astype(jnp.int32),
-      color_tab.astype(jnp.int32), deg_tab.astype(jnp.int32),
-      gid_tab.astype(jnp.int32), nlg)
-    return lose_v[:n].astype(bool), lose_o[:n].astype(bool), counts.sum()
+    t = lane_tile(tile, n, w)
+    n_pad = -(-n // t) * t
+    idx_t = pad_lanes(adj_cidx.astype(jnp.int32).T, n_pad, n_tab)
+    lose_v, lose_o, count = detect_block(
+        idx_t, color_tab.astype(jnp.int32)[idx_t],
+        deg_tab.astype(jnp.int32)[idx_t], gid_tab.astype(jnp.int32)[idx_t],
+        pad_lanes(colors.astype(jnp.int32), n_pad),
+        pad_lanes(deg.astype(jnp.int32), n_pad),
+        pad_lanes(gid.astype(jnp.int32), n_pad),
+        pad_lanes(is_boundary.astype(jnp.int32), n_pad),
+        n_loc=n_loc, n_tab=n_tab, recolor_degrees=recolor_degrees,
+        tile=t, interpret=interpret)
+    return lose_v[:n].astype(bool), lose_o[:, :n].astype(bool), count.sum()

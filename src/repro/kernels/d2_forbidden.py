@@ -1,15 +1,14 @@
-"""``d2_forbidden`` Pallas kernel — net-based two-hop forbidden accumulation.
+"""``d2_forbidden`` Pallas kernel — two-hop forbidden-mask accumulation.
 
 TPU adaptation of KokkosKernels ``NB_BIT`` (Taş et al. [22], Deveci [2]):
-instead of each vertex walking its full two-hop neighborhood from scratch
-(GPU warp-per-vertex), the kernel walks the *one*-hop ELL block and, per
-neighbor lane ``k``, gathers that neighbor's full adjacency row from the
-VMEM-resident extended adjacency table — a net-centric sweep expressed as
-``W`` dense row gathers instead of irregular pointer chasing.
-
-Produces the uint32 forbidden mask over the window ``[base, base+32)``
-covering one-hop (unless ``partial``) and two-hop colors; the ops.py
-wrapper combines it with the lowest-clear-bit pick (shared with vb_bit).
+instead of each vertex walking its two-hop neighborhood by pointer
+chasing (GPU warp-per-vertex), the wrapper expands the net in XLA — the
+one-hop ELL block indexes the extended adjacency, giving ``W²`` two-hop
+table indices per vertex — and gathers their colors into one dense
+lane-major ``(W + W², tile)`` block (``W²`` only for partial distance-2).
+The kernel OR-accumulates the ``uint32`` forbidden window over the block's
+rows (``vb_bit.forbidden_rows``); the ops.py wrapper combines it with the
+lowest-clear-bit pick shared with ``vb_bit``.
 """
 from __future__ import annotations
 
@@ -19,43 +18,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import default_interpret
+from repro.kernels import (default_interpret, block_spec, lane_tile,
+                           pad_lanes, row_spec)
+from repro.kernels.vb_bit import forbidden_rows
 
-DEFAULT_TILE = 128
+DEFAULT_TILE = 1024
+
+__all__ = ["d2_forbidden", "DEFAULT_TILE"]
 
 
-def _d2_kernel(partial_d2: bool, w: int,
-               adj_ref, base_ref, active_ref, colors_ref,
-               tab_ref, ext_adj_ref,
-               forbidden_ref):
-    adj = adj_ref[...]                     # (T, W) one-hop table indices
-    base = base_ref[...]                   # (T,)
-    active = active_ref[...]
-    colors = colors_ref[...]
-    tab = tab_ref[...]                     # (n_tab,)
-    ext = ext_adj_ref[...]                 # (n_tab, W) adjacency rows
-
-    uncolored = (active != 0) & (colors == 0)
-    base_eff = jnp.where(uncolored, base, 1)
-
-    def window_bits(nbr_colors):
-        rel = nbr_colors - base_eff[:, None]
-        in_w = (nbr_colors > 0) & (rel >= 0) & (rel < 32)
-        return jnp.where(in_w, jnp.uint32(1) << rel.astype(jnp.uint32), jnp.uint32(0))
-
-    if partial_d2:
-        forbidden = jnp.zeros(adj.shape[:1], jnp.uint32)
-    else:
-        forbidden = jnp.bitwise_or.reduce(window_bits(tab[adj]), axis=1)
-
-    def hop(k, acc):
-        u = jax.lax.dynamic_index_in_dim(adj, k, axis=1, keepdims=False)  # (T,)
-        row = ext[u]                       # (T, W) two-hop indices
-        bits = window_bits(tab[row])
-        return acc | jnp.bitwise_or.reduce(bits, axis=1)
-
-    forbidden = jax.lax.fori_loop(0, w, hop, forbidden)
-    forbidden_ref[...] = forbidden
+def _d2_kernel(nc_ref, base_ref, active_ref, colors_ref, forbidden_ref):
+    uncolored = (active_ref[...] != 0) & (colors_ref[...] == 0)
+    base_eff = jnp.where(uncolored, base_ref[...], 1)
+    forbidden_ref[...] = forbidden_rows(nc_ref, base_eff)
 
 
 @functools.partial(jax.jit, static_argnames=("partial_d2", "tile", "interpret"))
@@ -75,32 +50,24 @@ def d2_forbidden(
     if interpret is None:
         interpret = default_interpret()
     n, w = adj_cidx.shape
-    pad = (-n) % tile
-    pad_idx = color_tab.shape[0] - 1
-    if pad:
-        adj_cidx = jnp.pad(adj_cidx, ((0, pad), (0, 0)), constant_values=pad_idx)
-        base = jnp.pad(base, (0, pad), constant_values=1)
-        active = jnp.pad(active, (0, pad))
-        colors = jnp.pad(colors, (0, pad))
-    n_padded = n + pad
-    grid = (n_padded // tile,)
-
-    kernel = functools.partial(_d2_kernel, partial_d2, w)
+    # Slot-major throughout; the two-hop slots come out in (k2, k1) order,
+    # which the OR-reduction over slots does not see.
+    adj_t = adj_cidx.astype(jnp.int32).T
+    two_hop_t = ext_adj_cidx.astype(jnp.int32).T[:, adj_t].reshape(w * w, n)
+    idx = (two_hop_t if partial_d2
+           else jnp.concatenate([adj_t, two_hop_t], axis=0))
+    k = idx.shape[0]
+    t = lane_tile(tile, n, k)
+    n_pad = -(-n // t) * t
+    idx_t = pad_lanes(idx, n_pad, color_tab.shape[0] - 1)
+    row = lambda x, v=0: pad_lanes(x.astype(jnp.int32), n_pad, v).reshape(1, n_pad)  # noqa: E731
     forbidden = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, w), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec(color_tab.shape, lambda i: (0,)),
-            pl.BlockSpec(ext_adj_cidx.shape, lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_padded,), jnp.uint32),
+        _d2_kernel,
+        grid=(n_pad // t,),
+        in_specs=[block_spec(k, t)] + [row_spec(t)] * 3,
+        out_specs=row_spec(t),
+        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.uint32),
         interpret=interpret,
-    )(adj_cidx, base.astype(jnp.int32), active.astype(jnp.int32),
-      colors.astype(jnp.int32), color_tab.astype(jnp.int32),
-      ext_adj_cidx)
-    return forbidden[:n]
+    )(color_tab.astype(jnp.int32)[idx_t], row(base, 1), row(active),
+      row(colors))
+    return forbidden[0, :n]

@@ -1,35 +1,33 @@
-"""Fused speculate→detect round megakernel (one ``pallas_call`` per round).
+"""One fused detect→recolor inner round on dense lane-major kernels.
 
-The chained ``pallas`` backend runs one inner round as four separate
-programs — ``vb_bit``/``d2_forbidden`` assignment sweeps, ``pair_scatter``
-for received ghost updates, and ``conflict`` detection — each re-reading
-the full per-shard color table from HBM.  Following the single-pass
-structure of Taş & Kaya's optimistic coloring and KokkosKernels' fused
-GPU kernels (Deveci et al.), this kernel executes the *whole* round in
-one ``pallas_call``:
+The decomposed round (``core.distributed._detect_part`` then
+``_recolor_part``) is Alg-4 conflict detection against the freshly
+exchanged ghosts, zeroing of the losers, and speculative recoloring of the
+losers to a fixed point.  This module runs the same round with the
+elementwise work in three Mosaic kernels and the irregular memory traffic
+in XLA, because Mosaic cannot gather from a table by a 2-D index nor
+scatter inside a kernel:
 
-  1. optional inline scatter of received ``(slot, color)`` pairs into the
-     ghost segment (folds ``pair_scatter`` in — drop convention: slots
-     past the ghost count are padding);
-  2. tiled owned-vs-ghost conflict detection with the Alg-4 loser rule
-     (hash tie-breaking via ``v_loses``), accumulating the local lose
-     mask, the ghost-side lose table, and the conflict count;
-  3. losers are zeroed and speculatively recolored to a fixed point —
-     the windowed forbidden-bitmask assignment plus intra-part collision
-     resolution, iterated with an in-kernel ``lax.while_loop``.
+  1. XLA gathers neighbor colors / degrees / gids into lane-major
+     ``(K, N)`` blocks (``K`` = the ELL slots the problem reads: ``W`` for
+     d1, ``W + W²`` for d2, ``W²`` for pd2 — one concatenated index block
+     covers both the one- and two-hop sweeps) and the ``detect`` kernel
+     (``conflict.detect_block``) applies the Alg-4 loser rule;
+  2. XLA scatter-maxes the per-edge neighbor-side lose flags into the
+     ghost lose table;
+  3. :func:`speculate` iterates, to a fixed point, an XLA color gather,
+     the ``assign`` kernel (``vb_bit.assign_block``: windowed forbidden
+     mask + lowest clear bit), a second gather of the updated table, and
+     the ``resolve`` kernel (intra-part Alg-4 collisions; losers zeroed).
 
-The color table is materialized in VMEM once and every sweep is a tiled
-``fori_loop`` over row blocks (``dynamic_slice`` on row-major operands),
-so HBM sees one read of the table per round instead of four.  The math
-is lifted verbatim from the jnp reference (``core.local._speculate_round``
-and ``core.distributed._detect_part``), which keeps the fused path
-bit-identical to the decomposed one — ``fused_round_ref`` in
+The degree/gid gathers are loop invariant and paid once per call.  The
+kernels run a 1-D grid over ``tile``-lane row blocks
+(``kernels.lane_tile``), so VMEM holds one ``(K, tile)`` block per operand
+at any shard size; tables live in HBM.  The math is the jnp reference's
+(``core.local._speculate_round`` / ``core.conflict.v_loses``), which keeps
+the path bit-identical to the decomposed one — ``fused_round_ref`` in
 ``kernels/ref.py`` is the oracle and ``tests/test_kernels.py -k fused``
 pins parity on d1/d2/pd2 including ragged tails.
-
-VMEM working set: the full per-shard adjacency (and two-hop) blocks plus
-the color/deg/gid tables — same slab-shard ≤1M-vertex budget as
-``vb_bit.py``, with the two-hop block (n×W²) the dominant term for D2.
 """
 from __future__ import annotations
 
@@ -39,193 +37,113 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.conflict import v_loses
-from repro.core.local import forbidden_mask, pick_color
+from repro.core.conflict import lose_table, v_loses
+from repro.kernels import (default_interpret, block_spec, lane_tile,
+                           pad_lanes, row_spec)
+from repro.kernels.conflict import detect_block
+from repro.kernels.vb_bit import assign_block
 
-DEFAULT_TILE = 256
+DEFAULT_TILE = 2048
 
-# Ghost-lose accumulation: below this tile*width*n_ghost product the sweep
-# uses the scatter-free ballot-style iota-match reduction (the TPU idiom —
-# VPU compare+reduce, no serialized scatter); above it (huge D2 two-hop
-# blocks) it falls back to a clamped scatter into the (G+1,) ghost table.
-MATCH_LIMIT = 1 << 28
-
-__all__ = ["fused_round", "DEFAULT_TILE", "MATCH_LIMIT"]
+__all__ = ["fused_round", "speculate", "neighbor_index", "DEFAULT_TILE"]
 
 
-def _make_kernel(*, n, g, n_pad, tile, w, h2, problem, recolor_degrees,
-                 max_iters, has_pairs):
-    """Build the kernel body for one (shape, problem) configuration."""
-    needs_l2 = problem in ("d2", "pd2")
-    T = n_pad // tile
-    i32 = jnp.int32
+def neighbor_index(adj_cidx, two_hop_cidx, problem: str):
+    """The ``(N, K)`` color-table indices ``problem`` reads per vertex."""
+    if problem == "d1":
+        return adj_cidx
+    if two_hop_cidx is None:
+        raise ValueError(f"problem={problem!r} requires two_hop_cidx")
+    if problem == "pd2":
+        return two_hop_cidx
+    return jnp.concatenate([adj_cidx, two_hop_cidx], axis=1)
 
-    def kernel(*refs):
-        it = iter(refs)
-        adj_ref = next(it)
-        th_ref = next(it) if needs_l2 else None
-        colors_ref, ghost_ref, deg_ref, gid_ref, bnd_ref = (
-            next(it), next(it), next(it), next(it), next(it))
-        if has_pairs:
-            slots_ref, vals_ref = next(it), next(it)
-        out_colors_ref, out_lose_v_ref, out_lose_g_ref, count_ref = (
-            next(it), next(it), next(it), next(it))
 
-        adj = adj_ref[...]                       # (n_pad, w)
-        colors_in = colors_ref[...]              # (n,)
-        ghost = ghost_ref[...][:g]               # (g,)
-        deg_tab = deg_ref[...]                   # (n+g+1,)
-        gid_tab = gid_ref[...]
-        bnd = bnd_ref[...]                       # (n_pad,) int32 0/1
-        th = th_ref[...] if needs_l2 else None   # (n_pad, h2)
+def _resolve_kernel(recolor_degrees, nc_ref, nd_ref, ng_ref,
+                    cv_ref, dv_ref, gv_ref, act_ref, out_ref):
+    cv, dv, gv = cv_ref[...], dv_ref[...], gv_ref[...]     # (1, T)
 
-        if has_pairs:
-            # Inline pair_scatter: scatter-as-gather (slots are unique per
-            # exchange; slots >= g are padding and drop).
-            slots = slots_ref[...]
-            vals = vals_ref[...]
-            pos = jax.lax.broadcasted_iota(i32, (g, slots.shape[0]), 0)
-            match = pos == slots[None, :]
-            hit = match.any(axis=1)
-            val = jnp.where(match, vals[None, :], 0).sum(axis=1)
-            ghost = jnp.where(hit, val, ghost)
+    def body(k, lose):
+        row = pl.ds(k, 1)
+        hit = v_loses(cv, nc_ref[row, :], dv, nd_ref[row, :], gv,
+                      ng_ref[row, :], recolor_degrees=recolor_degrees)
+        return lose | hit.astype(jnp.int32)
 
-        padz = jnp.zeros((n_pad - n,), i32)
-        tab = jnp.concatenate([colors_in, ghost, jnp.zeros((1,), i32)])
-        colors_p = jnp.concatenate([colors_in, padz])
-        deg_rows = jnp.concatenate([deg_tab[:n], padz])
-        gid_rows = jnp.concatenate([gid_tab[:n], padz])
+    lose = jax.lax.fori_loop(0, nc_ref.shape[0], body,
+                             jnp.zeros(cv.shape, jnp.int32))
+    out_ref[...] = jnp.where((act_ref[...] != 0) & (lose != 0), 0, cv)
 
-        # -- 2. Alg-4 owned-vs-ghost conflict detection (tiled sweeps) ----
-        def sweep(adj_like, wk, carry):
-            use_match = g > 0 and tile * wk * g <= MATCH_LIMIT
 
-            def tbody(t, c):
-                lose_rows, lose_g, cnt = c
-                r0 = t * tile
-                a = jax.lax.dynamic_slice(adj_like, (r0, 0), (tile, wk))
-                cv = jax.lax.dynamic_slice(colors_p, (r0,), (tile,))
-                dv = jax.lax.dynamic_slice(deg_rows, (r0,), (tile,))
-                gv = jax.lax.dynamic_slice(gid_rows, (r0,), (tile,))
-                b = jax.lax.dynamic_slice(bnd, (r0,), (tile,))
-                is_ghost = (a >= n) & (a < n + g)
-                vl = v_loses(cv[:, None], tab[a], dv[:, None], deg_tab[a],
-                             gv[:, None], gid_tab[a],
-                             recolor_degrees=recolor_degrees) & is_ghost
-                ol = v_loses(tab[a], cv[:, None], deg_tab[a], dv[:, None],
-                             gid_tab[a], gv[:, None],
-                             recolor_degrees=recolor_degrees) & is_ghost
-                lr = (vl.any(axis=1) & (b != 0)).astype(i32)
-                prev = jax.lax.dynamic_slice(lose_rows, (r0,), (tile,))
-                lose_rows = jax.lax.dynamic_update_slice(
-                    lose_rows, prev | lr, (r0,))
-                if use_match:
-                    # Ballot-style reduction: ghost slot j lost iff any edge
-                    # of this tile with table index n+j carries ol — a VPU
-                    # compare+any, no scatter (same trick as the pair apply).
-                    gslot = jax.lax.broadcasted_iota(i32, (1, 1, g), 2)
-                    hit = ((a - n)[:, :, None] == gslot) & ol[:, :, None]
-                    lose_g = lose_g | jnp.pad(hit.any(axis=(0, 1)), (0, 1))
-                else:
-                    # Huge blocks (D2 two-hop at slab scale): clamped
-                    # scatter into the (G+1,) ghost table, pad slot last.
-                    idx = jnp.where(is_ghost, a - n, g)
-                    lose_g = lose_g.at[idx.reshape(-1)].max(ol.reshape(-1))
-                return lose_rows, lose_g, cnt + (vl | ol).sum().astype(i32)
+def _resolve_block(nc_t, nd_t, ng_t, colors, deg, gid, active, *,
+                   recolor_degrees, tile, interpret):
+    """Zero the active rows that lose an Alg-4 collision to any neighbor."""
+    k, n = nc_t.shape
+    row = lambda x: x.reshape(1, n)                       # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_resolve_kernel, recolor_degrees),
+        grid=(n // tile,),
+        in_specs=[block_spec(k, tile)] * 3 + [row_spec(tile)] * 4,
+        out_specs=row_spec(tile),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
+        interpret=interpret,
+    )(nc_t, nd_t, ng_t, row(colors), row(deg), row(gid), row(active))
+    return out[0]
 
-            return jax.lax.fori_loop(0, T, tbody, carry)
 
-        carry = (jnp.zeros((n_pad,), i32), jnp.zeros((g + 1,), bool),
-                 i32(0))
-        if problem != "pd2":
-            carry = sweep(adj, w, carry)
-        if needs_l2:
-            carry = sweep(th, h2, carry)
-        lose_rows, lose_ghost, cnt = carry
+def _lane_layout(idx, n_tab, tile):
+    """Lane tile, padded row count and the padded ``(K, N_pad)`` indices."""
+    n, k = idx.shape
+    t = lane_tile(tile, n, k)
+    n_pad = -(-n // t) * t
+    return t, n_pad, pad_lanes(idx.astype(jnp.int32).T, n_pad, n_tab - 1)
 
-        # -- 3. zero losers, speculate to a fixed point -------------------
-        active = lose_rows                       # (n_pad,) 0/1; pad rows 0
-        tab = tab.at[:n].set(jnp.where(lose_rows[:n] != 0, 0, colors_in))
-        base0 = jnp.ones((n_pad,), i32)
 
-        def cond(stv):
-            tab, _, it_ = stv
-            return (it_ < max_iters) & jnp.any(
-                (active[:n] != 0) & (tab[:n] == 0))
+@functools.partial(jax.jit, static_argnames=(
+    "recolor_degrees", "max_iters", "tile", "interpret"))
+def speculate(idx, color_tab, active, deg_tab, gid_tab, *,
+              recolor_degrees: bool = True, max_iters: int = 512,
+              tile: int = DEFAULT_TILE, interpret: bool | None = None):
+    """Speculative local coloring of ``active`` rows to a fixed point.
 
-        def body(stv):
-            tab, base, it_ = stv
-            rows_now = jnp.concatenate([tab[:n], padz])
+    ``idx (N, K)`` are the color-table indices each row must differ from
+    (``neighbor_index``); ``color_tab`` is the ``(n_tab,)`` table whose
+    first ``N`` entries are the rows.  Same contract and results as
+    ``core.local.local_color_d1`` (``idx = adj``) and ``local_color_d2``
+    (``idx`` = one- and two-hop, or two-hop only for pd2): returns the
+    updated table.
+    """
+    if interpret is None:
+        interpret = default_interpret()
+    n = active.shape[0]
+    color_tab = color_tab.astype(jnp.int32)
+    t, n_pad, idx_t = _lane_layout(idx, color_tab.shape[0], tile)
+    rest = color_tab[n:]                 # ghosts + pad slot: never written
+    nd = deg_tab.astype(jnp.int32)[idx_t]
+    ng = gid_tab.astype(jnp.int32)[idx_t]
+    dv = pad_lanes(deg_tab[:n].astype(jnp.int32), n_pad)
+    gv = pad_lanes(gid_tab[:n].astype(jnp.int32), n_pad)
+    act = pad_lanes(active.astype(jnp.int32), n_pad)
+    kw = dict(tile=t, interpret=interpret)
 
-            # Windowed assignment from the iteration-start snapshot.
-            def abody(t, c):
-                newc, newb = c
-                r0 = t * tile
-                a = jax.lax.dynamic_slice(adj, (r0, 0), (tile, w))
-                cv = jax.lax.dynamic_slice(rows_now, (r0,), (tile,))
-                act = jax.lax.dynamic_slice(active, (r0,), (tile,))
-                b = jax.lax.dynamic_slice(base, (r0,), (tile,))
-                uncolored = (act != 0) & (cv == 0)
-                base_eff = jnp.where(uncolored, b, 1)
-                if needs_l2:
-                    tht = jax.lax.dynamic_slice(th, (r0, 0), (tile, h2))
-                    if problem == "pd2":
-                        allc = tab[tht]
-                    else:
-                        allc = jnp.concatenate([tab[a], tab[tht]], axis=-1)
-                else:
-                    allc = tab[a]
-                m = forbidden_mask(allc, base_eff)
-                cand, ok = pick_color(m, base_eff)
-                nc = jnp.where(uncolored & ok, cand, cv)
-                nb = jnp.where(uncolored & ~ok, b + 32, b)
-                return (jax.lax.dynamic_update_slice(newc, nc, (r0,)),
-                        jax.lax.dynamic_update_slice(newb, nb, (r0,)))
+    def gather(colors):
+        return jnp.concatenate([colors[:n], rest])[idx_t]
 
-            newc, newb = jax.lax.fori_loop(0, T, abody, (rows_now, base))
-            tab = tab.at[:n].set(newc[:n])
+    def cond(st):
+        colors, _, it = st
+        return (it < max_iters) & jnp.any((act != 0) & (colors == 0))
 
-            # Intra-part Alg-4 collision resolution on the updated table.
-            def bbody(t, lose):
-                r0 = t * tile
-                a = jax.lax.dynamic_slice(adj, (r0, 0), (tile, w))
-                nc = jax.lax.dynamic_slice(newc, (r0,), (tile,))
-                act = jax.lax.dynamic_slice(active, (r0,), (tile,))
-                dv = jax.lax.dynamic_slice(deg_rows, (r0,), (tile,))
-                gv = jax.lax.dynamic_slice(gid_rows, (r0,), (tile,))
-                if needs_l2:
-                    tht = jax.lax.dynamic_slice(th, (r0, 0), (tile, h2))
-                    lose2 = v_loses(
-                        nc[:, None], tab[tht], dv[:, None], deg_tab[tht],
-                        gv[:, None], gid_tab[tht],
-                        recolor_degrees=recolor_degrees).any(axis=-1)
-                else:
-                    lose2 = jnp.zeros((tile,), bool)
-                if problem == "pd2":
-                    lose1 = jnp.zeros((tile,), bool)
-                else:
-                    lose1 = v_loses(
-                        nc[:, None], tab[a], dv[:, None], deg_tab[a],
-                        gv[:, None], gid_tab[a],
-                        recolor_degrees=recolor_degrees).any(axis=-1)
-                lz = ((act != 0) & (lose1 | lose2)).astype(i32)
-                return jax.lax.dynamic_update_slice(lose, lz, (r0,))
+    def body(st):
+        colors, base, it = st
+        newc, base = assign_block(gather(colors), colors, base, act, **kw)
+        colors = _resolve_block(gather(newc), nd, ng, newc, dv, gv, act,
+                                recolor_degrees=recolor_degrees, **kw)
+        return colors, base, it + 1
 
-            lose = jax.lax.fori_loop(0, T, bbody, jnp.zeros((n_pad,), i32))
-            tab = tab.at[:n].set(jnp.where(lose[:n] != 0, 0, newc[:n]))
-            return tab, newb, it_ + 1
-
-        tab, _, _ = jax.lax.while_loop(cond, body, (tab, base0, i32(0)))
-
-        out_colors_ref[...] = tab[:n]
-        out_lose_v_ref[...] = lose_rows[:n]
-        if g:
-            out_lose_g_ref[...] = lose_ghost[:g].astype(i32)
-        else:
-            out_lose_g_ref[...] = jnp.zeros((1,), i32)
-        count_ref[0] = cnt
-
-    return kernel
+    colors0 = pad_lanes(color_tab[:n], n_pad)
+    base0 = jnp.ones((n_pad,), jnp.int32)
+    colors, _, _ = jax.lax.while_loop(cond, body,
+                                      (colors0, base0, jnp.int32(0)))
+    return jnp.concatenate([colors[:n], rest])
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -252,58 +170,43 @@ def fused_round(
     Returns ``(new_colors (N,), lose_v (N,) bool, lose_ghost (G,) bool,
     n_conflicts scalar int32)`` — exactly the decomposed
     ``_detect_part`` + ``_recolor_part`` composition of the reference
-    backend (``fused_round_ref`` is the pinned oracle).
+    backend (``fused_round_ref`` is the pinned oracle).  Optional
+    ``(pair_slots, pair_colors)`` ghost updates (slots ``>= G`` drop) are
+    applied before detection.
     """
-    from repro.kernels import default_interpret
-
     if interpret is None:
         interpret = default_interpret()
-    if max_iters is None:
-        max_iters = 512 if problem == "d1" else 1024
     if problem not in ("d1", "d2", "pd2"):
         raise ValueError(f"fused_round does not support problem={problem!r}")
-    n, w = adj_cidx.shape
-    g = ghost.shape[0]
-    pad_cidx = n + g
-    pad = (-n) % tile
-    n_pad = n + pad
+    if max_iters is None:
+        max_iters = 512 if problem == "d1" else 1024
+    n, g = colors.shape[0], ghost.shape[0]
+    colors = colors.astype(jnp.int32)
+    ghost = ghost.astype(jnp.int32)
+    if pair_slots is not None:
+        ghost = ghost.at[pair_slots].set(pair_colors.astype(jnp.int32),
+                                         mode="drop")
+    deg_tab = deg_tab.astype(jnp.int32)
+    gid_tab = gid_tab.astype(jnp.int32)
+    zero = jnp.zeros((1,), jnp.int32)
+    tab = jnp.concatenate([colors, ghost, zero])
 
-    def pad_rows(x, value=0):
-        if not pad:
-            return x
-        cfg = ((0, pad),) + ((0, 0),) * (x.ndim - 1)
-        return jnp.pad(x, cfg, constant_values=value)
+    # -- 1+2. Alg-4 owned-vs-ghost detection; ghost-side losers scattered.
+    idx = neighbor_index(adj_cidx, two_hop_cidx, problem)
+    t, n_pad, idx_t = _lane_layout(idx, n + g + 1, tile)
+    lose_v, lose_o, count = detect_block(
+        idx_t, tab[idx_t], deg_tab[idx_t], gid_tab[idx_t],
+        pad_lanes(colors, n_pad), pad_lanes(deg_tab[:n], n_pad),
+        pad_lanes(gid_tab[:n], n_pad),
+        pad_lanes(is_boundary.astype(jnp.int32), n_pad),
+        n_loc=n, n_tab=n + g, recolor_degrees=recolor_degrees,
+        tile=t, interpret=interpret)
+    lose_l = lose_v[:n] != 0
+    lose_g = lose_table(idx_t, lose_o, n + g + 1)[n:n + g] != 0
 
-    adj_p = pad_rows(adj_cidx.astype(jnp.int32), pad_cidx)
-    bnd_p = pad_rows(is_boundary.astype(jnp.int32))
-    inputs = [adj_p]
-    h2 = 0
-    if problem in ("d2", "pd2"):
-        if two_hop_cidx is None:
-            raise ValueError(f"problem={problem!r} requires two_hop_cidx")
-        h2 = two_hop_cidx.shape[1]
-        inputs.append(pad_rows(two_hop_cidx.astype(jnp.int32), pad_cidx))
-    ghost_in = ghost.astype(jnp.int32) if g else jnp.zeros((1,), jnp.int32)
-    inputs += [colors.astype(jnp.int32), ghost_in,
-               deg_tab.astype(jnp.int32), gid_tab.astype(jnp.int32), bnd_p]
-    has_pairs = pair_slots is not None
-    if has_pairs:
-        inputs += [pair_slots.astype(jnp.int32),
-                   pair_colors.astype(jnp.int32)]
-
-    kernel = _make_kernel(
-        n=n, g=g, n_pad=n_pad, tile=tile, w=w, h2=h2, problem=problem,
-        recolor_degrees=recolor_degrees, max_iters=max_iters,
-        has_pairs=has_pairs)
-    new_colors, lose_v, lose_g, count = pl.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((max(g, 1),), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*inputs)
-    return (new_colors, lose_v.astype(bool), lose_g[:g].astype(bool),
-            count[0])
+    # -- 3. zero losers, speculate them to a fixed point. --------------
+    tab = jnp.concatenate([jnp.where(lose_l, 0, colors), ghost, zero])
+    tab = speculate(idx, tab, lose_l, deg_tab, gid_tab,
+                    recolor_degrees=recolor_degrees, max_iters=max_iters,
+                    tile=tile, interpret=interpret)
+    return tab[:n], lose_l, lose_g, count.sum()

@@ -2,8 +2,10 @@
 
 ``local_color_d1_pallas`` / ``local_color_d2_pallas`` are drop-in
 replacements for ``repro.core.local.local_color_d1`` / ``local_color_d2``
-built from the kernels: assignment (vb_bit / d2_forbidden) + speculative-
-collision resolution iterated to a fixed point.  The distributed runtime
+built from the kernels: assignment + speculative-collision resolution
+iterated to a fixed point.  d1 is ``fused_round.speculate`` (the
+``vb_bit`` assign and ``resolve`` kernels, the loop every fused round
+runs); d2 assigns through ``d2_forbidden``.  The distributed runtime
 selects them through the pluggable backend layer —
 ``color_distributed(..., backend="pallas")`` routes every local-coloring
 and conflict-detection step through these wrappers (see
@@ -25,7 +27,7 @@ from repro.kernels import default_interpret
 from repro.kernels.conflict import conflict_detect
 from repro.kernels.d2_forbidden import d2_forbidden
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.fused_round import fused_round
+from repro.kernels.fused_round import DEFAULT_TILE, fused_round, speculate
 from repro.kernels.scatter import pair_scatter
 from repro.kernels.vb_bit import vb_bit_assign
 
@@ -42,47 +44,16 @@ __all__ = [
 ]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("recolor_degrees", "max_iters", "interpret", "tile")
-)
 def local_color_d1_pallas(
     adj_cidx, color_tab, active, deg_tab, gid_tab, *,
     recolor_degrees: bool = True, max_iters: int = 512,
-    interpret: bool | None = None, tile: int = 256,
+    interpret: bool | None = None, tile: int = DEFAULT_TILE,
 ):
-    """Kernel-backed distance-1 local coloring (same contract as core.local)."""
-    if interpret is None:
-        interpret = default_interpret()
-    n_loc = active.shape[0]
-    base0 = jnp.ones((n_loc,), jnp.int32) + 0 * color_tab[:n_loc]
-    deg_loc = deg_tab[:n_loc]
-    gid_loc = gid_tab[:n_loc]
-
-    def cond(st):
-        tab, base, it = st
-        return (it < max_iters) & jnp.any(active & (tab[:n_loc] == 0))
-
-    def body(st):
-        tab, base, it = st
-        colors, base = vb_bit_assign(
-            adj_cidx, tab[:n_loc], base, active, tab,
-            tile=tile, interpret=interpret,
-        )
-        tab = tab.at[:n_loc].set(colors)
-        # Intra-tile speculative collisions: Alg-4 rule over ALL neighbors
-        # (not only ghosts), reusing the jnp rule — the conflict kernel's
-        # ghost-scoped variant is exercised by the distributed detect path.
-        co = tab[adj_cidx]
-        do = deg_tab[adj_cidx]
-        go = gid_tab[adj_cidx]
-        lose = v_loses(colors[:, None], co, deg_loc[:, None], do,
-                       gid_loc[:, None], go,
-                       recolor_degrees=recolor_degrees).any(axis=1)
-        tab = tab.at[:n_loc].set(jnp.where(active & lose, 0, colors))
-        return tab, base, it + 1
-
-    color_tab, _, _ = jax.lax.while_loop(cond, body, (color_tab, base0, jnp.int32(0)))
-    return color_tab
+    """Kernel-backed distance-1 local coloring (same contract as core.local):
+    the ``fused_round.speculate`` fixed point over the one-hop rows."""
+    return speculate(adj_cidx, color_tab, active, deg_tab, gid_tab,
+                     recolor_degrees=recolor_degrees, max_iters=max_iters,
+                     tile=tile, interpret=interpret)
 
 
 @functools.partial(
@@ -131,6 +102,9 @@ def local_color_d2_pallas(
     base0 = jnp.ones((n_loc,), jnp.int32) + 0 * color_tab[:n_loc]
     deg_loc = deg_tab[:n_loc]
     gid_loc = gid_tab[:n_loc]
+    # Resolution neighborhood, slot-major: two-hop, plus one-hop unless pd2.
+    idx = (two_hop_cidx.T if partial_d2
+           else jnp.concatenate([adj_cidx.T, two_hop_cidx.T], axis=0))
 
     def cond(st):
         tab, base, it = st
@@ -143,21 +117,11 @@ def local_color_d2_pallas(
             partial_d2=partial_d2, tile=tile, interpret=interpret,
         )
         tab = tab.at[:n_loc].set(colors)
-        lose2 = v_loses(
-            colors[:, None], tab[two_hop_cidx], deg_loc[:, None],
-            deg_tab[two_hop_cidx], gid_loc[:, None], gid_tab[two_hop_cidx],
-            recolor_degrees=recolor_degrees,
-        ).any(axis=-1)
-        if partial_d2:
-            lose1 = jnp.zeros_like(lose2)
-        else:
-            lose1 = v_loses(
-                colors[:, None], tab[adj_cidx], deg_loc[:, None],
-                deg_tab[adj_cidx], gid_loc[:, None], gid_tab[adj_cidx],
-                recolor_degrees=recolor_degrees,
-            ).any(axis=-1)
-        lose = active & (lose1 | lose2)
-        tab = tab.at[:n_loc].set(jnp.where(lose, 0, colors))
+        lose = v_loses(
+            colors[None], tab[idx], deg_loc[None], deg_tab[idx],
+            gid_loc[None], gid_tab[idx], recolor_degrees=recolor_degrees,
+        ).any(axis=0)
+        tab = tab.at[:n_loc].set(jnp.where(active & lose, 0, colors))
         return tab, base, it + 1
 
     color_tab, _, _ = jax.lax.while_loop(cond, body, (color_tab, base0, jnp.int32(0)))

@@ -45,7 +45,7 @@ def conflict_detect_ref(adj_cidx, colors, deg, gid, is_boundary,
                  recolor_degrees=recolor_degrees) & is_ghost
     lose_v = vl.any(axis=1) & is_boundary.astype(bool)
     count = (vl | ol).sum().astype(jnp.int32)
-    return lose_v, ol, count
+    return lose_v, ol.T, count          # neighbor flags slot-major (W, N)
 
 
 def d2_forbidden_ref(adj_cidx, base, active, colors, color_tab, ext_adj_cidx,
@@ -76,7 +76,7 @@ def fused_round_ref(adj_cidx, colors, ghost, deg_tab, gid_tab, is_boundary,
                     ext_adj_cidx=None, *, problem="d1", recolor_degrees=True):
     """Oracle for kernels.fused_round.fused_round.
 
-    The decomposed composition the megakernel fuses: optional
+    The decomposed composition the fused round reproduces: optional
     ``pair_scatter`` into the ghost segment, then the reference
     ``_detect_part`` sweep, then zero-losers + ``_recolor_part``.
     ``ext_adj_cidx`` is only threaded through for the d2 recolor

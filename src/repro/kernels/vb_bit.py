@@ -2,20 +2,15 @@
 
 TPU adaptation of KokkosKernels ``VB_BIT`` (Deveci et al. [2]):
 GPU version: one warp per vertex walks a CSR row, ballot-builds a 64-bit
-forbidden mask.  TPU version: a *tile* of ``TILE`` vertices is processed per
-grid step; the ELL-padded neighbor block ``(TILE, W)`` makes the neighbor
-color gather a dense lookup into the VMEM-resident color table, and the
-forbidden mask is a ``uint32`` window accumulated with VPU bitwise ops —
-no ballots, no atomics (DESIGN.md §2).
+forbidden mask.  TPU version: the neighbor colors arrive as a dense
+lane-major ``(W, tile)`` block (one vertex per lane, one ELL slot per
+sublane row — the gather ran in XLA, since Mosaic has no in-kernel
+table gather), and the ``uint32`` forbidden window is OR-accumulated row
+by row with VPU bitwise ops — no ballots, no atomics.
 
-VMEM working set per grid step:
-  adj tile      TILE×W×4 B
-  color table   (n_tab)×4 B      (the per-shard table: owned+ghost+pad)
-  base/active/colors tiles  3×TILE×4 B
-With TILE=256, W≤128, n_tab≤1M this is ≈4.3 MB — comfortably inside the
-~16 MB/core VMEM budget of v5e; larger shards stream the table (documented
-limitation: we target slab shards ≤1M vertices, matching the paper's
-100M-vertices-per-GPU at HBM scale but VMEM-resident color windows).
+VMEM working set per grid step: the ``(W, tile)`` color block plus four
+``(1, tile)`` row vectors, double buffered; :func:`repro.kernels.lane_tile`
+sizes ``tile`` so any ELL width fits.  The table itself stays in HBM.
 """
 from __future__ import annotations
 
@@ -25,36 +20,62 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import default_interpret
+from repro.core.local import pick_color
+from repro.kernels import (default_interpret, block_spec, lane_tile,
+                           pad_lanes, row_spec)
 
-DEFAULT_TILE = 256
+DEFAULT_TILE = 2048
+
+__all__ = ["vb_bit_assign", "assign_block", "forbidden_rows", "DEFAULT_TILE"]
 
 
-def _vb_bit_kernel(adj_ref, colors_ref, base_ref, active_ref, tab_ref,
+def forbidden_rows(nc_ref, base_eff):
+    """OR the window bits of every row of a ``(K, tile)`` color block.
+
+    ``base_eff (1, tile)`` are the window starts; colors ``<= 0`` (pad,
+    uncolored) never forbid.  Mirrors ``core.local.forbidden_mask``.
+    """
+    one, zero = jnp.uint32(1), jnp.uint32(0)
+
+    def body(k, acc):
+        c = nc_ref[pl.ds(k, 1), :]
+        rel = c - base_eff
+        in_w = (c > 0) & (rel >= 0) & (rel < 32)
+        shift = jnp.where(in_w, rel, 0).astype(jnp.uint32)
+        return acc | jnp.where(in_w, one << shift, zero)
+
+    return jax.lax.fori_loop(0, nc_ref.shape[0], body,
+                             jnp.zeros(base_eff.shape, jnp.uint32))
+
+
+def _assign_kernel(nc_ref, colors_ref, base_ref, active_ref,
                    out_colors_ref, out_base_ref):
-    """One grid step: assign colors to a tile of vertices."""
-    adj = adj_ref[...]                      # (T, W) int32 indices into table
-    colors = colors_ref[...]                # (T,)  current colors of the tile
-    base = base_ref[...]                    # (T,)  window starts
-    active = active_ref[...]                # (T,)  int32 0/1 mask
-    tab = tab_ref[...]                      # (n_tab,) full color table
-
-    nbr_colors = tab[adj]                   # dense VMEM gather
-    uncolored = (active != 0) & (colors == 0)
+    colors = colors_ref[...]                 # (1, T)
+    base = base_ref[...]
+    uncolored = (active_ref[...] != 0) & (colors == 0)
     base_eff = jnp.where(uncolored, base, 1)
-
-    rel = nbr_colors - base_eff[:, None]
-    in_window = (nbr_colors > 0) & (rel >= 0) & (rel < 32)
-    bits = jnp.where(in_window, jnp.uint32(1) << rel.astype(jnp.uint32), jnp.uint32(0))
-    forbidden = jnp.bitwise_or.reduce(bits, axis=1)
-
-    t = (~forbidden) & (forbidden + jnp.uint32(1))
-    ok = t != 0
-    bitpos = jax.lax.population_count(t - jnp.uint32(1)).astype(jnp.int32)
-    cand = base_eff + jnp.where(ok, bitpos, 0)
-
+    cand, ok = pick_color(forbidden_rows(nc_ref, base_eff), base_eff)
     out_colors_ref[...] = jnp.where(uncolored & ok, cand, colors)
     out_base_ref[...] = jnp.where(uncolored & ~ok, base + 32, base)
+
+
+def assign_block(nc_t, colors, base, active, *, tile, interpret):
+    """Dense assignment over lane-padded inputs.
+
+    ``nc_t (K, N)`` neighbor colors, ``colors/base/active (N,)`` int32,
+    with ``N`` a multiple of ``tile``.  Returns ``(colors, base)``.
+    """
+    k, n = nc_t.shape
+    row = lambda x: x.astype(jnp.int32).reshape(1, n)     # noqa: E731
+    out = pl.pallas_call(
+        _assign_kernel,
+        grid=(n // tile,),
+        in_specs=[block_spec(k, tile)] + [row_spec(tile)] * 3,
+        out_specs=[row_spec(tile)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32)] * 2,
+        interpret=interpret,
+    )(nc_t, row(colors), row(base), row(active))
+    return out[0][0], out[1][0]
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -72,34 +93,14 @@ def vb_bit_assign(
     if interpret is None:
         interpret = default_interpret()
     n, w = adj_cidx.shape
-    pad = (-n) % tile
-    if pad:
-        adj_cidx = jnp.pad(adj_cidx, ((0, pad), (0, 0)), constant_values=color_tab.shape[0] - 1)
-        colors = jnp.pad(colors, (0, pad))
-        base = jnp.pad(base, (0, pad), constant_values=1)
-        active = jnp.pad(active, (0, pad))
-    n_pad = n + pad
-    grid = (n_pad // tile,)
-
-    out_colors, out_base = pl.pallas_call(
-        _vb_bit_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, w), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec(color_tab.shape, lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(adj_cidx, colors.astype(jnp.int32), base.astype(jnp.int32),
-      active.astype(jnp.int32), color_tab.astype(jnp.int32))
-    return out_colors[:n], out_base[:n]
+    t = lane_tile(tile, n, w)
+    n_pad = -(-n // t) * t
+    idx_t = pad_lanes(adj_cidx.astype(jnp.int32).T, n_pad,
+                      color_tab.shape[0] - 1)
+    c, b = assign_block(
+        color_tab.astype(jnp.int32)[idx_t],
+        pad_lanes(colors.astype(jnp.int32), n_pad),
+        pad_lanes(base.astype(jnp.int32), n_pad, 1),
+        pad_lanes(active.astype(jnp.int32), n_pad),
+        tile=t, interpret=interpret)
+    return c[:n], b[:n]

@@ -1,77 +1,55 @@
-"""Persistent jax compilation cache wiring (opt-in).
+"""Persistent jax compilation cache wiring.
 
-At scale the dominant cold-start cost of a coloring plan is the XLA
-compile, not the host-state build; jax can persist compiled executables
-to disk (``jax_compilation_cache_dir``) so a relaunch on the same
-topology/config key pays host-state build only.  This module is the one
-place that knob is set — the CLI (``launch/color.py``) and the serving
-frontend (``serve/coloring.py``) both call :func:`enable_compilation_cache`
-before building plans.
+The cold path of a coloring plan is dominated by the XLA compile of its
+loop program (and, on TPU, the Mosaic compile of its kernels); jax can
+persist compiled executables to disk so a relaunch on the same
+topology/config key pays the host-state build only.  This module is the
+one place that knob is set — the CLI (``launch/color.py``), the serving
+frontend (``serve/coloring.py``) and ``chip_smoke.py`` call
+:func:`enable_compilation_cache` before their first compile.
 
-The cache is **opt-in on this jax pin**: it engages only when a ``path``
-is passed explicitly or env ``REPRO_COMPILATION_CACHE_DIR`` is set to a
-directory (empty string or ``0`` keeps it off).  Pinned jax 0.4.37 has a
-CPU bug where executables restored from the persistent cache lose their
-input-donation aliasing metadata — a later host read of an array that
-aliased a donated input segfaults (reproducible with the train loop's
-``donate_argnums`` step under ``JAX_COMPILATION_CACHE_DIR``) — so the
-default must stay off until the pin moves.  Measured win when enabled:
-a CLI relaunch on the same topology drops from ~5.3s to ~2.4s solve
-time on the toy hex mesh.
+Where the cache lives:
 
-Idempotent per process (jax config updates are global); safe on jax
-versions lacking the persistent-cache knobs (silently a no-op).
+* ``JAX_COMPILATION_CACHE_DIR`` set — jax already reads it; the directory
+  is left exactly as given and no other path is set in code.
+* unset — one fixed directory inside the checkout, :data:`DEFAULT_DIR`
+  (``<repo>/.jax_cache``, git-ignored), so a relaunch from the same
+  checkout finds what the last one compiled; it never depends on a temp
+  dir, pid or time.
+
+``jax_enable_compilation_cache`` is left alone, so a caller that turned
+the cache off (the test suite's ``conftest.py``) keeps it off.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
-__all__ = ["enable_compilation_cache"]
+__all__ = ["enable_compilation_cache", "DEFAULT_DIR"]
 
-_ENV = "REPRO_COMPILATION_CACHE_DIR"
-_configured: str | None = None
+DEFAULT_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def enable_compilation_cache(path: str | None = None) -> str | None:
-    """Point jax's persistent compilation cache at ``path`` (or env
-    ``REPRO_COMPILATION_CACHE_DIR``; unset/empty/``0`` = disabled — see
-    the module docstring for why the default is off on this jax pin).
-    Returns the directory in use, or ``None`` when disabled.  Once per
-    process: later calls return the first configuration without touching
-    jax config again.
+def enable_compilation_cache() -> str:
+    """Point jax's persistent compilation cache at its directory.
+
+    Returns the directory in use: ``$JAX_COMPILATION_CACHE_DIR`` when set,
+    else :data:`DEFAULT_DIR`.  Idempotent.
     """
-    global _configured
-    if _configured is not None:
-        return _configured or None
-    if path is None:
-        path = os.environ.get(_ENV, "")
-    if not path or path == "0":
-        _configured = ""
-        return None
-    path = os.path.abspath(os.path.expanduser(path))
-    os.makedirs(path, exist_ok=True)
-
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", path)
-    try:
-        # Persist every executable, however fast it compiled: the plans
-        # this repo builds are many small programs, and the default
-        # min-compile-time threshold would skip most of them.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except AttributeError:  # knob not present on this jax version
-        pass
-    try:
-        # jax initializes its cache state at most once, on the first
-        # compile; if any compile ran before this call (imports often
-        # trigger tiny ones), that one-shot init latched "disabled".
-        # Reset so the next compile re-initializes against ``path``.
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc,
-        )
+    path = os.environ.get(_ENV) or DEFAULT_DIR
+    if jax.config.jax_compilation_cache_dir != path:
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+        # jax initializes its cache at most once, on the first compile; a
+        # compile that ran before this call latched the old setting.
+        from jax.experimental.compilation_cache import compilation_cache
 
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - shape varies across versions
-        pass
-    _configured = path
+        compilation_cache.reset_cache()
+    # Persist every executable, however fast it compiled: the plans this
+    # repo builds are many small programs, and the default min-compile-time
+    # threshold would skip most of them.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return path

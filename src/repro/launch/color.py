@@ -1,15 +1,16 @@
 """Distributed-coloring driver (the paper's workload as a CLI).
 
   PYTHONPATH=src python -m repro.launch.color --graph hex:24,24,24 \
-      --parts 8 --problem d1 [--no-recolor-degrees] [--backend pallas] \
+      --parts 8 --problem d1 [--no-recolor-degrees] [--backend reference] \
       [--exchange halo|delta|sparse_delta] [--baseline] [--repeat 16]
 
 Graph specs: hex:NX,NY,NZ | grid:NX,NY | rmat:SCALE,EF | rgg:N,R |
 myc:K | er:N,DEG | bip:ROWS,COLS,NNZ
 
---backend selects the local-compute backend (reference jnp path, the
-chained Pallas kernels, or ``pallas_fused`` — one megakernel per inner
-round); --exchange the ghost-exchange strategy, where ``delta``
+--backend selects the local-compute backend (``pallas_fused``, the
+default — one jitted round on dense Mosaic kernels, interpret mode on the
+CPU backend —, the chained ``pallas`` kernels, or the ``reference`` jnp
+path); --exchange the ghost-exchange strategy, where ``delta``
 ships only boundary colors that changed since the previous round and
 ``sparse_delta`` routes them as count-prefixed (slot, color) pairs over
 edge-colored ppermute phases — for both, the reported comm/round is the
@@ -146,7 +147,7 @@ def main() -> None:
                     choices=["d1", "d1_2gl", "d2", "pd2"])
     ap.add_argument("--strategy", default="block",
                     choices=["block", "edge_balanced", "random"])
-    ap.add_argument("--backend", default="reference",
+    ap.add_argument("--backend", default="pallas_fused",
                     choices=list_backends())
     ap.add_argument("--exchange", default="all_gather",
                     choices=list_exchanges())
@@ -169,11 +170,9 @@ def main() -> None:
                     help="class-rebuild order used by --reduce-passes")
     args = ap.parse_args()
 
-    # Persistent XLA compilation cache: relaunching the same topology /
-    # config pays host-state build only.  Opt-in — engages only when
-    # REPRO_COMPILATION_CACHE_DIR names a directory (the pinned jax
-    # loses donation aliasing on cache-restored CPU executables, so the
-    # default stays off; see launch/cache.py).
+    # Persistent XLA compilation cache ($JAX_COMPILATION_CACHE_DIR, else
+    # <repo>/.jax_cache): relaunching the same topology / config pays the
+    # host-state build only.
     enable_compilation_cache()
 
     if args.stream:

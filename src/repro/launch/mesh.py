@@ -14,12 +14,19 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (tests, small runs)."""
-    return jax.make_mesh(shape, axes)
+    """The one mesh constructor: every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, whose sharding-in-
+    types rules the GSPMD-style code here (``with_sharding_constraint``,
+    ``shard_map`` with untyped collectives) is not written against.
+    """
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:
@@ -65,4 +72,4 @@ def make_two_level_mesh(n_parts: int, node_size: int | None = None):
     devices enumerate node-major (the default on TPU slices).
     """
     n_nodes, node_size = factor_parts(n_parts, node_size)
-    return jax.make_mesh((n_nodes, node_size), ("node", "local"))
+    return make_mesh((n_nodes, node_size), ("node", "local"))

@@ -387,10 +387,11 @@ class _SlotGroup:
                 return []
             self._start_wave(stats, count=count)
         self._fill_slots(stats, count=count)
-        step = self._program(self._steps, self.plan.slot_step, (0,), stats,
-                             self.carry)
+        st = self.plan.state
+        step = self._program(self._steps, self.plan.slot_step, (1,), stats,
+                             st, self.carry)
         t0 = time.perf_counter()
-        self.carry, done = step(self.carry)
+        self.carry, done = step(st, self.carry)
         done = np.asarray(done)
         stats.warm_ms_total += (time.perf_counter() - t0) * 1e3
         finished = []
@@ -449,7 +450,8 @@ class _SlotGroup:
             ticket, req = nxt
             self.fe._note_running(ticket)
             c0, g0, a0, _ = self.plan.request_inputs(**req.plan_inputs())
-            args = (np.int32(i),) + self.plan.slot_args(c0, g0, a0)
+            args = ((np.int32(i),) + self.plan.slot_args(c0, g0, a0)
+                    + (self._ex_init,))
             refill = self._program(
                 self._refills, lambda: self.plan.slot_refill(self._ex_init),
                 (0,), stats, self.carry, *args)
@@ -552,11 +554,9 @@ class ColoringFrontend:
         compilation_cache: bool = True,
     ):
         if compilation_cache:
-            # Persistent XLA compilation cache: a frontend restart on the
-            # same topologies pays host-state build only.  Opt-in — a
-            # no-op unless REPRO_COMPILATION_CACHE_DIR names a directory
-            # (the pinned jax drops donation aliasing on cache-restored
-            # CPU executables; see launch/cache.py).
+            # Persistent XLA compilation cache (launch/cache.py): a
+            # frontend restart on the same topologies pays host-state
+            # build only.
             from repro.launch.cache import enable_compilation_cache
 
             enable_compilation_cache()

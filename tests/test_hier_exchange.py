@@ -3,14 +3,13 @@
 Covers the tentpole contracts: ``hier_delta`` is bit-identical to
 ``all_gather`` across problems and backends, its measured bytes carry
 the ``[intra-node, inter-node]`` split, wire widths are the narrowest
-the static bounds admit, and the ragged transport gate behaves on the
-pinned jax.  The shard_map-engine legs live in test_multidevice.py.
+the static bounds admit, and the sparse transport is an explicit
+choice.  The shard_map-engine legs live in test_multidevice.py.
 """
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.core.distributed import build_device_state, color_distributed
 from repro.core.exchange import (
     COLOR_DTYPE,
@@ -253,6 +252,8 @@ def test_wire_widths_wide_send_slots():
 # ---------------------------------------------------------------------------
 
 def test_compilation_cache_wiring(monkeypatch, tmp_path):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says; unset, to the
+    one fixed directory inside the checkout — never a temp/pid path."""
     import os
 
     import jax
@@ -260,50 +261,49 @@ def test_compilation_cache_wiring(monkeypatch, tmp_path):
     from repro.launch import cache as cache_mod
 
     old_dir = jax.config.jax_compilation_cache_dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     try:
-        # Opt-in: unset env means disabled on this jax pin (see
-        # launch/cache.py for the donation-aliasing segfault it avoids).
-        monkeypatch.setattr(cache_mod, "_configured", None)
-        monkeypatch.delenv("REPRO_COMPILATION_CACHE_DIR", raising=False)
-        assert cache_mod.enable_compilation_cache() is None
-        monkeypatch.setattr(cache_mod, "_configured", None)
-        monkeypatch.setenv("REPRO_COMPILATION_CACHE_DIR", "")
-        assert cache_mod.enable_compilation_cache() is None
-        monkeypatch.setattr(cache_mod, "_configured", None)
-        target = str(tmp_path / "cc")
-        assert cache_mod.enable_compilation_cache(target) == target
-        assert os.path.isdir(target)
-        assert jax.config.jax_compilation_cache_dir == target
-        # Once per process: later calls return the first configuration.
-        assert cache_mod.enable_compilation_cache("/elsewhere") == target
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert cache_mod.DEFAULT_DIR == os.path.join(repo, ".jax_cache")
+        assert cache_mod.enable_compilation_cache() == cache_mod.DEFAULT_DIR
+        assert jax.config.jax_compilation_cache_dir == cache_mod.DEFAULT_DIR
+        assert os.path.isdir(cache_mod.DEFAULT_DIR)
+        # Idempotent, and the same fixed path on every call.
+        assert cache_mod.enable_compilation_cache() == cache_mod.DEFAULT_DIR
+        # An outside placement wins and is left exactly as given.
+        outside = str(tmp_path / "cc")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        assert cache_mod.enable_compilation_cache() == outside
+        assert jax.config.jax_compilation_cache_dir == outside
+        # The suite's own switch (conftest.py) is never flipped back on.
+        assert jax.config.jax_enable_compilation_cache is False
     finally:
         jax.config.update("jax_compilation_cache_dir", old_dir)
 
 
 # ---------------------------------------------------------------------------
-# Ragged all-to-all gate on the pinned jax.
+# Sparse transport: an explicit choice, never switched by platform.
 # ---------------------------------------------------------------------------
 
 def test_ragged_transport_gate():
-    assert SparseDeltaExchange(ragged=False)._use_ragged() is False
-    auto = SparseDeltaExchange(ragged="auto")
-    assert auto._use_ragged() == compat.has_ragged_all_to_all()
-    if not compat.has_ragged_all_to_all():
-        with pytest.raises(RuntimeError, match="ragged_all_to_all"):
-            SparseDeltaExchange(ragged=True)._use_ragged()
-    else:
-        assert SparseDeltaExchange(ragged=True)._use_ragged() is True
+    assert SparseDeltaExchange().ragged is False          # phase loop
+    assert SparseDeltaExchange(ragged=True).ragged is True
+    with pytest.raises(ValueError, match="ragged"):
+        SparseDeltaExchange(ragged="auto")
 
 
 def test_ragged_auto_falls_back_bit_identical():
-    """``ragged="auto"`` must match the forced phase loop wherever it
-    lands (fallback on the pinned jax, ragged transport on newer)."""
+    """The simulate view has no wire: both transport choices (and the
+    default) give the same colors, rounds and measured bytes."""
     loop = color_distributed(PG, problem="d1", engine="simulate",
                              exchange=SparseDeltaExchange(ragged=False),
                              cache=False)
-    auto = color_distributed(PG, problem="d1", engine="simulate",
-                             exchange=SparseDeltaExchange(ragged="auto"),
-                             cache=False)
-    assert (auto.colors == loop.colors).all()
-    assert auto.rounds == loop.rounds
-    assert auto.comm_bytes_total == loop.comm_bytes_total
+    rag = color_distributed(PG, problem="d1", engine="simulate",
+                            exchange=SparseDeltaExchange(ragged=True),
+                            cache=False)
+    dflt = color_distributed(PG, problem="d1", engine="simulate",
+                             exchange="sparse_delta", cache=False)
+    for other in (rag, dflt):
+        assert (other.colors == loop.colors).all()
+        assert other.rounds == loop.rounds
+        assert other.comm_bytes_total == loop.comm_bytes_total

@@ -96,7 +96,7 @@ assert sd.comm_bytes_total < ag.comm_bytes_total
 assert list(sd.comm_bytes_by_round) == list(sd_sim.comm_bytes_by_round)
 
 # Pallas backends round-trip d2/pd2 through shard_map + sparse a2a too
-# (chained kernels AND the fused round megakernel).
+# (chained kernels AND the fused round).
 for problem in ("d2", "pd2"):
     p_ref = color_distributed(pg, problem=problem, engine="simulate")
     for backend in ("pallas", "pallas_fused"):
@@ -125,7 +125,6 @@ from repro.graph.partition import two_level_partition
 from repro.core.distributed import color_distributed
 from repro.core.exchange import SparseDeltaExchange
 from repro.core.validate import is_proper_d1, is_proper_d2
-from repro import compat
 
 g = hex_mesh(12, 6, 6)
 pg = two_level_partition(g, 2, 2, second_layer=True)
@@ -153,20 +152,74 @@ hd = color_distributed(pg, problem="d1", engine="shard_map",
                        exchange="hier_delta")
 assert hd.comm_bytes_total < sd.comm_bytes_total < ag.comm_bytes_total
 
-# Ragged transport: bit-identical to the phase loop when this jax has
-# lax.ragged_all_to_all; a clean RuntimeError when it does not.
-if compat.has_ragged_all_to_all():
-    rg = color_distributed(pg, problem="d1", engine="shard_map",
-                           exchange=SparseDeltaExchange(ragged=True))
-    assert (rg.colors == sd.colors).all()
-    assert rg.comm_bytes_total == sd.comm_bytes_total
-else:
-    try:
-        color_distributed(pg, problem="d1", engine="shard_map",
-                          exchange=SparseDeltaExchange(ragged=True))
-        raise SystemExit("ragged=True should have raised")
-    except RuntimeError:
-        pass
+# Transport is an explicit choice: the default sparse_delta program is the
+# ppermute phase loop; ragged=True lowers to one ragged all-to-all (which
+# XLA:CPU cannot run, so only its lowering is checked here — the chip
+# smoke runs it).
+from repro.core.plan import build_plan
+import jax
+def lowered(exchange):
+    plan = build_plan(pg, problem="d1", engine="shard_map",
+                      exchange=exchange)
+    c0, g0, a0, seed = plan.request_inputs()
+    return plan._fn.lower(plan.state, c0, g0, a0, seed).as_text()
+loop_txt, rag_txt = lowered("sparse_delta"), lowered(
+    SparseDeltaExchange(ragged=True))
+assert "ragged_all_to_all" not in loop_txt and "collective_permute" in loop_txt
+assert "ragged_all_to_all" in rag_txt
+print("OK")
+""", devices=4)
+    assert "OK" in out
+
+
+def test_ragged_transport_emulated_matches_phase_loop():
+    """``SparseDeltaExchange(ragged=True)`` on a 4-device mesh, with
+    ``lax.ragged_all_to_all`` replaced by an all_gather emulation of its
+    documented semantics (XLA:CPU cannot run the real collective): the
+    packed rows must carry every counted pair inside the trimmed
+    ``send_sizes`` prefix, so colors, rounds and bytes equal the phase
+    loop's."""
+    out = run_py("""
+import jax
+import jax.numpy as jnp
+from repro.graph.generators import hex_mesh
+from repro.graph.partition import partition_graph
+from repro.core.distributed import color_distributed
+from repro.core.exchange import SparseDeltaExchange
+
+
+def emulated(operand, output, input_offsets, send_sizes, output_offsets,
+             recv_sizes, *, axis_name):
+    # One slice per peer: source j's slice for this device starts at its
+    # input_offsets[me] and lands at its output_offsets[me] (sender side).
+    me = jax.lax.axis_index(axis_name)
+    ops = jax.lax.all_gather(operand, axis_name)
+    col = lambda x: jax.lax.all_gather(x, axis_name)[:, me]
+    in_off, size, out_off = col(input_offsets), col(send_sizes), col(
+        output_offsets)
+    k = jnp.arange(output.shape[0])
+    for j in range(ops.shape[0]):
+        rel = k - out_off[j]
+        hit = (rel >= 0) & (rel < size[j])
+        src = jnp.clip(in_off[j] + rel, 0, ops.shape[1] - 1)
+        output = jnp.where(hit, ops[j][src], output)
+    return output
+
+
+jax.lax.ragged_all_to_all = emulated
+g = hex_mesh(12, 8, 8)
+pg = partition_graph(g, 4, second_layer=True)
+for problem in ("d1", "d2", "pd2"):
+    loop = color_distributed(pg, problem=problem, engine="shard_map",
+                             exchange=SparseDeltaExchange(), cache=False)
+    rag = color_distributed(pg, problem=problem, engine="shard_map",
+                            exchange=SparseDeltaExchange(ragged=True),
+                            cache=False)
+    assert loop.rounds >= 1, problem
+    assert (rag.colors == loop.colors).all(), problem
+    assert rag.rounds == loop.rounds, problem
+    assert (list(rag.comm_bytes_by_round)
+            == list(loop.comm_bytes_by_round)), problem
 print("OK")
 """, devices=4)
     assert "OK" in out

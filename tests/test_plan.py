@@ -260,7 +260,7 @@ def test_warm_run_no_retrace_hier_delta(monkeypatch):
 
 
 def test_warm_run_no_retrace_pallas_fused(monkeypatch):
-    """The megakernel backend honours the same compile-once contract:
+    """The fused-round backend honours the same compile-once contract:
     warm ``plan.run()`` never rebuilds host state or retraces."""
     plan = build_plan(PG, problem="d2", backend="pallas_fused",
                       engine="simulate")
