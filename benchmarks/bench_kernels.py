@@ -105,7 +105,7 @@ def run(toy: bool = False) -> list[str]:
     rounds = {}
     for name in ("reference", "pallas", "pallas_fused"):
         b = get_backend(name)
-        (colored), us_c = timed(lambda b=b: b.color_d1(
+        (colored, _), us_c = timed(lambda b=b: b.color_d1(
             adj, tab0, active, deg_tab, gid_tab, recolor_degrees=True))
         outs[name] = np.asarray(colored)
         rows.append(row(f"backend/{name}/color_d1", us_c,
@@ -114,7 +114,7 @@ def run(toy: bool = False) -> list[str]:
             adj, tab[:nl], tab, deg_tab, gid_tab, boundary,
             recolor_degrees=True))
         rows.append(row(f"backend/{name}/detect", us_d, "alg4_sweep"))
-        (c2), us_2 = timed(lambda b=b: b.color_d2(
+        (c2, _), us_2 = timed(lambda b=b: b.color_d2(
             adj, two_hop, ext, tab0, active, deg_tab, gid_tab,
             partial_d2=False, recolor_degrees=True))
         rows.append(row(f"backend/{name}/color_d2", us_2,
@@ -128,7 +128,7 @@ def run(toy: bool = False) -> list[str]:
               & (outs["reference"] == outs["pallas_fused"]).all())
     rows.append(row("backend/parity/color_d1", 0, f"identical={ok}"))
     ok = all(bool((rounds["reference"][i] == rounds[name][i]).all())
-             for name in ("pallas", "pallas_fused") for i in range(4))
+             for name in ("pallas", "pallas_fused") for i in range(5))
     rows.append(row("backend/parity/round_d1", 0, f"identical={ok}"))
 
     # HBM bytes of the *lowered* one-round programs: both are jitted over

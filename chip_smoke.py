@@ -104,13 +104,15 @@ def timed_plan(phase, dev, pg, *, backend, exchange, engine="simulate",
 
     plan = get_plan(pg, problem=problem, backend=backend, exchange=exchange,
                     engine=engine, **kw)
+    t0 = time.perf_counter()
     res = plan.run()
+    run_ms = (time.perf_counter() - t0) * 1e3
     report(phase, backend=backend, exchange=getattr(exchange, "name", exchange),
            engine=plan.key.engine, device_kind=dev.device_kind,
            build_ms=f"{plan.stats.build_ms:.1f}",
            compile_ms=f"{plan.stats.compile_ms:.1f}",
-           run_ms=f"{plan.stats.last_run_ms - plan.stats.compile_ms:.1f}",
-           rounds=res.rounds, colors=res.n_colors,
+           run_ms=f"{run_ms - plan.stats.compile_ms:.1f}",
+           rounds=res.rounds, spec_iters=res.spec_iters, colors=res.n_colors,
            comm_bytes_total=res.comm_bytes_total,
            peak_bytes_in_use=peak_bytes(dev))
     return plan, res

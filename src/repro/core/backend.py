@@ -52,13 +52,15 @@ class LocalBackend:
 
     def color_d1(self, adj_cidx, color_tab, active, deg_tab, gid_tab, *,
                  recolor_degrees: bool):
-        """Distance-1 speculative coloring of ``active`` rows; returns the
-        updated color table."""
+        """Distance-1 speculative coloring of ``active`` rows; returns
+        ``(color_table, iters)``: the updated table and the number of
+        speculative iterations its fixed point took."""
         raise NotImplementedError
 
     def color_d2(self, adj_cidx, two_hop_cidx, ext_adj_cidx, color_tab, active,
                  deg_tab, gid_tab, *, partial_d2: bool, recolor_degrees: bool):
-        """Distance-2 / partial-distance-2 speculative coloring."""
+        """Distance-2 / partial-distance-2 speculative coloring; returns
+        ``(color_table, iters)`` like :meth:`color_d1`."""
         raise NotImplementedError
 
     def detect(self, adj_cidx, colors_loc, color_tab, deg_tab, gid_tab,
@@ -79,11 +81,12 @@ class LocalBackend:
         for the next round.
 
         Returns ``(new_colors (nl,), lose_loc (nl,) bool, lose_ghost (G,)
-        bool, n_conflicts scalar int32)``.  The default implementation is
-        the decomposed ``_detect_part`` → ``_recolor_part`` composition,
-        so ``reference`` and plain ``pallas`` stay bit-identical oracles
-        for backends that override this with a fused kernel
-        (``pallas_fused``).
+        bool, n_conflicts scalar int32, iters scalar int32)``, ``iters``
+        being the recolor's speculative iterations.  The default
+        implementation is the decomposed ``_detect_part`` →
+        ``_recolor_part`` composition, so ``reference`` and plain
+        ``pallas`` stay bit-identical oracles for backends that override
+        this with a fused kernel (``pallas_fused``).
         """
         from repro.core.distributed import _detect_part, _recolor_part
 
@@ -92,9 +95,9 @@ class LocalBackend:
         lose_l, lose_g, conf = _detect_part(st, colors_loc, ghost_colors,
                                             **kw)
         colors = jnp.where(lose_l, 0, colors_loc)
-        colors = _recolor_part(st, colors, ghost_colors, lose_l, lose_g,
-                               **kw)
-        return colors, lose_l, lose_g, conf
+        colors, iters = _recolor_part(st, colors, ghost_colors, lose_l,
+                                      lose_g, **kw)
+        return colors, lose_l, lose_g, conf, iters
 
 
 class ReferenceBackend(LocalBackend):

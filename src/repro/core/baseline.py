@@ -76,7 +76,7 @@ def color_baseline(
     no_ghost_active = jnp.zeros_like(st["ghost_real"])
 
     # Phase 1: interior only — provably conflict-free (paper §3, Bozdağ).
-    colors = recolor(st, colors, zeros_g, jnp.asarray(interior), no_ghost_active)
+    colors, _ = recolor(st, colors, zeros_g, jnp.asarray(interior), no_ghost_active)
     ghost = exchange(colors)
 
     rounds, total = 0, 0
@@ -85,7 +85,7 @@ def color_baseline(
     for b in range(n_batches):
         active = jnp.asarray(boundary & (batch_of == b)) | lose_l
         colors = jnp.where(lose_l, 0, colors)
-        colors = recolor(st, colors, ghost, active, no_ghost_active)
+        colors, _ = recolor(st, colors, ghost, active, no_ghost_active)
         ghost = exchange(colors)
         lose_l, _, conf = detect(st, colors, ghost)
         total += int(conf.sum())
@@ -94,7 +94,7 @@ def color_baseline(
     conf_g = int(np.asarray(lose_l).sum())
     while conf_g > 0 and rounds < max_rounds:
         colors = jnp.where(lose_l, 0, colors)
-        colors = recolor(st, colors, ghost, lose_l, no_ghost_active)
+        colors, _ = recolor(st, colors, ghost, lose_l, no_ghost_active)
         ghost = exchange(colors)
         lose_l, _, conf = detect(st, colors, ghost)
         conf_g = int(conf.sum())

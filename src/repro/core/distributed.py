@@ -78,6 +78,11 @@ class ColoringResult:
     # hosts); hier_delta measures the two levels separately.  None under
     # the same conditions as comm_bytes_by_round.
     comm_bytes_by_level: np.ndarray | None = None
+    # Speculative iterations of the local coloring (the while loop of
+    # ``fused_round.speculate`` / ``core.local``) summed over the
+    # request's recolor and rounds; the most any part ran.  0 for
+    # runtimes that do not count them (baseline / Jones-Plassmann).
+    spec_iters: int = 0
 
     @property
     def comm_bytes_intra(self) -> int:
@@ -155,18 +160,19 @@ def build_device_state(pg: PartitionedGraph, problem: str) -> dict[str, np.ndarr
 def _recolor_part(st, colors_loc, ghost_colors, active_loc, active_ghost, *,
                   problem: str, recolor_degrees: bool,
                   backend: LocalBackend | None = None):
-    """Recolor active vertices of one part; returns new local colors."""
+    """Recolor active vertices of one part; returns ``(new local colors,
+    speculative iterations)``."""
     backend = backend or _REFERENCE
     n_loc = colors_loc.shape[0]
     zero = jnp.zeros((1,), jnp.int32)
     color_tab = jnp.concatenate([colors_loc, ghost_colors, zero])
     if problem in ("d2", "pd2"):
-        color_tab = backend.color_d2(
+        color_tab, iters = backend.color_d2(
             st["adj_cidx"], st["two_hop_cidx"], st["ext_adj_cidx"],
             color_tab, active_loc, st["deg_tab"], st["gid_tab"],
             partial_d2=(problem == "pd2"), recolor_degrees=recolor_degrees,
         )
-        return color_tab[:n_loc]
+        return color_tab[:n_loc], iters
     if problem == "d1_2gl":
         # Locals + conflicted ghosts recolor together over the extended
         # adjacency; ghosts' speculative colors inform locals (paper §3.4)
@@ -176,17 +182,17 @@ def _recolor_part(st, colors_loc, ghost_colors, active_loc, active_ghost, *,
         tab = jnp.concatenate(
             [colors_loc, jnp.where(active_ghost, 0, ghost_colors), zero]
         )
-        tab = backend.color_d1(
+        tab, iters = backend.color_d1(
             st["ext_adj_cidx"][: n_loc + n_ghost], tab, active_ext,
             st["deg_tab"], st["gid_tab"], recolor_degrees=recolor_degrees,
         )
-        return tab[:n_loc]
+        return tab[:n_loc], iters
     # plain d1
-    color_tab = backend.color_d1(
+    color_tab, iters = backend.color_d1(
         st["adj_cidx"], color_tab, active_loc, st["deg_tab"], st["gid_tab"],
         recolor_degrees=recolor_degrees,
     )
-    return color_tab[:n_loc]
+    return color_tab[:n_loc], iters
 
 
 def _detect_part(st, colors_loc, ghost_colors, *, problem: str,
@@ -230,7 +236,8 @@ def _detect_part(st, colors_loc, ghost_colors, *, problem: str,
 def _round_part(st, colors_loc, ghost_colors, *, problem: str,
                 recolor_degrees: bool, backend: LocalBackend | None = None):
     """One fused inner round of one part: detect → zero losers →
-    speculative recolor for the next round (``LocalBackend.round``)."""
+    speculative recolor for the next round (``LocalBackend.round``);
+    returns ``(colors, lose_l, lose_g, n_conflicts, iters)``."""
     backend = backend or _REFERENCE
     return backend.round(st, colors_loc, ghost_colors, problem=problem,
                          recolor_degrees=recolor_degrees)
@@ -248,10 +255,18 @@ def _make_loop(recolor, round_fn, exchange, all_sum, *, max_rounds: int):
     ``simulate`` engine binds ``vmap``-ped steps + a stacked gather — so
     they provably execute identical math.
 
-      recolor(colors, ghost, active_local, active_ghost) -> colors
-      round_fn(colors, ghost) -> (colors, lose_local, lose_ghost, n_confl)
+      recolor(colors, ghost, active_local, active_ghost) -> (colors, iters)
+      round_fn(colors, ghost) -> (colors, lose_local, lose_ghost, n_confl,
+                                  iters)
       exchange(colors, ex_state) -> (ghost, payload_bytes, ex_state)
       all_sum(x) -> global scalar (psum / sum over the part axis)
+
+    ``iters`` counts the speculative iterations of a part's local
+    coloring; the loop sums them over the request's recolor and rounds
+    in the ``iters`` carry entry, kept per part (no collective), and
+    returns that sum last.  Every ``exchange`` call runs under the
+    ``exchange`` named scope and every ``round_fn`` call under ``round``,
+    so a profile attributes device time to those layers.
 
     ``round_fn`` fuses conflict detection with the *next* round's
     speculative recoloring (``LocalBackend.round``): detect round k and
@@ -262,10 +277,19 @@ def _make_loop(recolor, round_fn, exchange, all_sum, *, max_rounds: int):
     identity, so the returned colors equal the unrotated loop's.
     """
 
+    def exchange_(colors, ex_state):
+        with jax.named_scope("exchange"):
+            return exchange(colors, ex_state)
+
+    def round_(colors, ghost):
+        with jax.named_scope("round"):
+            return round_fn(colors, ghost)
+
     def loop(colors0, zeros_ghost, active0, no_ghost_active, ex_state0):
-        colors = recolor(colors0, zeros_ghost, active0, no_ghost_active)
-        ghost, nbytes, ex_state = exchange(colors, ex_state0)
-        colors, lose_l, lose_g, conf = round_fn(colors, ghost)
+        colors, iters0 = recolor(colors0, zeros_ghost, active0,
+                                 no_ghost_active)
+        ghost, nbytes, ex_state = exchange_(colors, ex_state0)
+        colors, lose_l, lose_g, conf, iters = round_(colors, ghost)
         conf = all_sum(conf)
         # Byte history carries the [intra-node, inter-node] split per
         # round (flat strategies are booked as inter; see level_split).
@@ -275,14 +299,15 @@ def _make_loop(recolor, round_fn, exchange, all_sum, *, max_rounds: int):
             "colors": colors, "ghost": ghost, "lose_l": lose_l,
             "lose_g": lose_g, "ex_state": ex_state, "conf": conf,
             "rounds": jnp.int32(0), "total": conf, "bytes": bytes_hist,
+            "iters": iters0 + iters,
         }
 
         def cond(c):
             return (c["conf"] > 0) & (c["rounds"] < max_rounds)
 
         def body(c):
-            ghost, nbytes, ex_state = exchange(c["colors"], c["ex_state"])
-            colors, lose_l, lose_g, conf = round_fn(c["colors"], ghost)
+            ghost, nbytes, ex_state = exchange_(c["colors"], c["ex_state"])
+            colors, lose_l, lose_g, conf, iters = round_(c["colors"], ghost)
             conf = all_sum(conf)
             rounds = c["rounds"] + 1
             return {
@@ -290,6 +315,7 @@ def _make_loop(recolor, round_fn, exchange, all_sum, *, max_rounds: int):
                 "lose_g": lose_g, "ex_state": ex_state, "conf": conf,
                 "rounds": rounds, "total": c["total"] + conf,
                 "bytes": c["bytes"].at[rounds].set(level_split(nbytes)),
+                "iters": c["iters"] + iters,
             }
 
         # The batched recoloring service vmaps this loop over a request
@@ -299,7 +325,7 @@ def _make_loop(recolor, round_fn, exchange, all_sum, *, max_rounds: int):
         # (pinned by tests/test_plan.py::test_service_batch_bit_identical).
         out = jax.lax.while_loop(cond, body, carry)
         return (out["colors"], out["rounds"], out["conf"], out["total"],
-                out["bytes"])
+                out["bytes"], out["iters"])
 
     return loop
 

@@ -109,8 +109,12 @@ def local_color_d1(
     *,
     recolor_degrees: bool = True,
     max_iters: int = 512,
-) -> jnp.ndarray:
-    """Distance-1 speculative local coloring. Returns the updated table."""
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Distance-1 speculative local coloring.
+
+    Returns ``(table, iters)``: the updated table and the number of
+    speculate+resolve iterations the fixed point took (int32 scalar).
+    """
     n_loc = active.shape[0]
     # ``+ 0 * color_tab`` ties the carry's varying-axis type to the data so
     # the same code works under shard_map (varying) and plain jit.
@@ -128,8 +132,9 @@ def local_color_d1(
         )
         return color_tab, base, it + 1
 
-    color_tab, _, _ = jax.lax.while_loop(cond, body, (color_tab, base0, jnp.int32(0)))
-    return color_tab
+    color_tab, _, iters = jax.lax.while_loop(
+        cond, body, (color_tab, base0, jnp.int32(0)))
+    return color_tab, iters
 
 
 @partial(jax.jit, static_argnames=("partial_d2", "recolor_degrees", "max_iters"))
@@ -144,8 +149,9 @@ def local_color_d2(
     partial_d2: bool = False,
     recolor_degrees: bool = True,
     max_iters: int = 1024,
-) -> jnp.ndarray:
-    """Distance-2 (or partial-distance-2) speculative local coloring."""
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Distance-2 (or partial-distance-2) speculative local coloring;
+    returns ``(table, iters)`` like :func:`local_color_d1`."""
     n_loc = active.shape[0]
     base0 = jnp.ones((n_loc,), jnp.int32) + 0 * color_tab[:n_loc]  # vma tie
 
@@ -162,8 +168,9 @@ def local_color_d2(
         )
         return color_tab, base, it + 1
 
-    color_tab, _, _ = jax.lax.while_loop(cond, body, (color_tab, base0, jnp.int32(0)))
-    return color_tab
+    color_tab, _, iters = jax.lax.while_loop(
+        cond, body, (color_tab, base0, jnp.int32(0)))
+    return color_tab, iters
 
 
 def build_two_hop(adj_cidx: jnp.ndarray, full_adj_cidx: jnp.ndarray) -> jnp.ndarray:

@@ -40,6 +40,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.compat import shard_map as _shard_map
 from repro.core.backend import LocalBackend, get_backend
@@ -65,7 +66,17 @@ __all__ = [
     "plan_key_for",
     "default_plan_cache",
     "cached_device_state",
+    "PLAN_SPANS",
 ]
+
+# Host spans of ``ColoringPlan.run`` (``jax.profiler.TraceAnnotation``), in
+# the order a request passes them: host-side request inputs, their
+# transfers, the ahead-of-time compile (first run only), the call of the
+# compiled program, the wait for its outputs, and the device-to-host copy
+# and assembly of the ``ColoringResult``.  A profile of the process puts
+# them on the device trace's clock.
+PLAN_SPANS = ("plan.inputs", "plan.transfer", "plan.compile",
+              "plan.dispatch", "plan.wait", "plan.fetch")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +99,6 @@ class PlanStats:
     traces: int = 0             # times the loop program was (re)traced
     runs: int = 0               # plan.run() invocations
     build_ms: float = 0.0       # host-side static-half cost (state + prepare)
-    last_run_ms: float = 0.0
     compiles: int = 0           # ahead-of-time lower+compile events
     compile_ms: float = 0.0     # total time spent tracing + compiling
 
@@ -183,11 +193,12 @@ def _build_simulate_step(strategy: ExchangeStrategy, backend: LocalBackend, *,
     def step(st, carry):
         stats.traces += 1       # python side effect: fires only at trace time
         fresh = carry["rounds"] < 0
-        colors = recolor(st, carry["colors"], carry["ghost"],
-                         carry["lose_l"] & fresh, carry["lose_g"] & fresh)
+        colors, iters0 = recolor(st, carry["colors"], carry["ghost"],
+                                 carry["lose_l"] & fresh,
+                                 carry["lose_g"] & fresh)
         ghost, nbytes, ex_state = strategy.stacked(st, colors,
                                                    carry["ex_state"])
-        colors, lose_l, lose_g, conf = round_(st, colors, ghost)
+        colors, lose_l, lose_g, conf, iters = round_(st, colors, ghost)
         conf = jnp.sum(conf)
         rounds = carry["rounds"] + 1
         return {
@@ -195,6 +206,7 @@ def _build_simulate_step(strategy: ExchangeStrategy, backend: LocalBackend, *,
             "lose_g": lose_g, "ex_state": ex_state, "conf": conf,
             "rounds": rounds, "total": carry["total"] + conf,
             "bytes": carry["bytes"].at[rounds].set(level_split(nbytes)),
+            "iters": carry["iters"] + iters0 + iters,
         }
 
     return step
@@ -231,14 +243,14 @@ def _build_shard_map_step(strategy: ExchangeStrategy, backend: LocalBackend, *,
 
         def one(c):
             fresh = c["rounds"] < 0
-            colors = _recolor_part(st1, c["colors"][0], c["ghost"][0],
-                                   c["lose_l"][0] & fresh,
-                                   c["lose_g"][0] & fresh, **step_kw)
+            colors, iters0 = _recolor_part(
+                st1, c["colors"][0], c["ghost"][0], c["lose_l"][0] & fresh,
+                c["lose_g"][0] & fresh, **step_kw)
             ex_state = tree_util.tree_map(lambda x: x[0], c["ex_state"])
             ghost, nbytes, ex_state = strategy.device(
                 st1, colors, ex_state, axis="p", n_parts=n_parts)
-            colors, lose_l, lose_g, conf = _round_part(st1, colors, ghost,
-                                                       **step_kw)
+            colors, lose_l, lose_g, conf, iters = _round_part(
+                st1, colors, ghost, **step_kw)
             conf = jax.lax.psum(conf, "p")
             rounds = c["rounds"] + 1
             new = {
@@ -248,6 +260,7 @@ def _build_shard_map_step(strategy: ExchangeStrategy, backend: LocalBackend, *,
                 "conf": conf, "rounds": rounds,
                 "total": c["total"] + conf,
                 "bytes": c["bytes"].at[rounds].set(level_split(nbytes)),
+                "iters": c["iters"] + (iters0 + iters)[None],
             }
             # Finished slots still ride the (batched) collectives but
             # their carries are frozen — bit-identical to solo runs.
@@ -282,6 +295,7 @@ def _slot_refill_core(carry, slot, c0, g0, a0, ex_init):
     out["rounds"] = carry["rounds"].at[slot].set(-1)
     out["total"] = carry["total"].at[slot].set(0)
     out["bytes"] = carry["bytes"].at[slot].set(0)
+    out["iters"] = carry["iters"].at[slot].set(0)
     return out
 
 
@@ -316,11 +330,12 @@ def _build_shard_map_fn(strategy: ExchangeStrategy, backend: LocalBackend, *,
             partial(jax.lax.psum, axis_name="p"),
             max_rounds=max_rounds,
         )
-        colors, rounds, conf, total, nbytes = loop(
+        colors, rounds, conf, total, nbytes, iters = loop(
             c[0], g0[0], a0[0], jnp.zeros_like(st["ghost_real"]),
             strategy.init_state(st),
         )
-        return colors[None], rounds, conf, total, nbytes
+        # The iteration count stays per part: no collective for a counter.
+        return colors[None], rounds, conf, total, nbytes, iters[None]
 
     specs = {k: PS("p") for k in st_keys}
     f = jax.jit(
@@ -328,7 +343,7 @@ def _build_shard_map_fn(strategy: ExchangeStrategy, backend: LocalBackend, *,
             device_fn,
             mesh=mesh,
             in_specs=(specs, PS("p"), PS("p"), PS("p"), PS()),
-            out_specs=(PS("p"), PS(), PS(), PS(), PS()),
+            out_specs=(PS("p"), PS(), PS(), PS(), PS(), PS("p")),
         ),
         donate_argnums=(1,),
     )
@@ -377,7 +392,6 @@ class ColoringPlan:
         # into the compiled program.
         self._active0 = st_np.pop("active0")
         st_np.update(strategy.prepare(pg, st_np))
-        self._st = {k: jnp.asarray(v) for k, v in st_np.items()}
 
         kw = dict(problem=key.problem, recolor_degrees=key.recolor_degrees,
                   max_rounds=key.max_rounds, stats=self.stats)
@@ -400,9 +414,13 @@ class ColoringPlan:
             # Upload the static tables once, already laid out over the
             # mesh: without this every plan.run() implicitly re-shards
             # (re-transfers) the whole state dict into the executable.
+            # Each part's rows go straight from the host to their device;
+            # staging the stack on one device first would hold every
+            # part's tables there at once.
             self._st = jax.device_put(
-                self._st, NamedSharding(mesh, PartitionSpec("p")))
+                st_np, NamedSharding(mesh, PartitionSpec("p")))
         else:
+            self._st = {k: jnp.asarray(v) for k, v in st_np.items()}
             self.raw_fn = _build_simulate_fn(strategy, backend, **kw)
             self.raw_step = _build_simulate_step(strategy, backend, **kw)
             # The device-resident tables are the first argument; per-run
@@ -456,6 +474,7 @@ class ColoringPlan:
             "colors": part, "ghost": part, "lose_l": part, "lose_g": part,
             "ex_state": jax.tree_util.tree_map(lambda _: part, ex_init),
             "conf": PS(), "rounds": PS(), "total": PS(), "bytes": PS(),
+            "iters": part,
         }
 
     def slot_ex_init(self):
@@ -485,6 +504,7 @@ class ColoringPlan:
             "rounds": jnp.full((bucket,), mr, jnp.int32),
             "total": jnp.zeros((bucket,), jnp.int32),
             "bytes": jnp.zeros((bucket, mr + 1, 2), jnp.int32),
+            "iters": jnp.zeros((bucket, p), jnp.int32),
         }
         if self.key.engine != "shard_map":
             return carry
@@ -572,13 +592,22 @@ class ColoringPlan:
         shardings on every call.
         """
         if self.key.engine == "shard_map":
-            from jax.sharding import NamedSharding, PartitionSpec as PS
-
-            ns = NamedSharding(self._mesh, PS("p"))
-            return (jax.device_put(jnp.asarray(c0), ns),
-                    jax.device_put(jnp.asarray(g0), ns),
-                    jax.device_put(jnp.asarray(a0), ns))
+            return self._put_inputs(c0, g0, a0)
         return (jnp.asarray(c0), jnp.asarray(g0), jnp.asarray(a0))
+
+    def _put_inputs(self, *inputs):
+        """Explicit transfers of stacked ``(P, ...)`` request inputs.
+
+        On ``shard_map`` each part's rows go straight to its device, laid
+        out like the static tables; a plain ``device_put`` would land the
+        whole stack on one chip and leave the executable to re-shard it.
+        """
+        if self._mesh is None:
+            return tuple(jax.device_put(x) for x in inputs)
+        from jax.sharding import NamedSharding, PartitionSpec as PS
+
+        ns = NamedSharding(self._mesh, PS("p"))
+        return tuple(jax.device_put(x, ns) for x in inputs)
 
     def run(self, color_mask=None, colors0=None, seed=None) -> ColoringResult:
         """Execute one recoloring request through the compiled program.
@@ -591,30 +620,39 @@ class ColoringPlan:
         deterministic and ignore it.
 
         All three are dynamic inputs: no host-side state rebuild, no
-        retrace (the carry buffer is donated to the program).
+        retrace (the carry buffer is donated to the program).  Each phase
+        runs under its host span of :data:`PLAN_SPANS`.
         """
-        t0 = time.perf_counter()
-        c0, g0, active0, seed_ = self.request_inputs(color_mask, colors0, seed)
+        inputs, transfer, compile_, dispatch, wait, fetch = PLAN_SPANS
+        with TraceAnnotation(inputs):
+            c0, g0, active0, seed_ = self.request_inputs(color_mask, colors0,
+                                                         seed)
         # Explicit transfers of the per-request inputs only — the static
         # tables are a device-resident dict (sharded over the mesh on
         # shard_map); warm runs move no table bytes
         # (pinned by the transfer-guard probe in tests/test_plan.py).
-        args = (self._st, jax.device_put(c0), jax.device_put(g0),
-                jax.device_put(active0), jax.device_put(seed_))
+        with TraceAnnotation(transfer):
+            args = (self._st, *self._put_inputs(c0, g0, active0),
+                    jax.device_put(seed_))
         if self._compiled is None:
             # Ahead-of-time split: trace+compile cost lands in
             # ``stats.compile_ms`` so serving accounting can book it as
             # cold and attribute the execution below to the warm path.
-            self._compiled, dt = aot_compile(self._fn, *args)
+            with TraceAnnotation(compile_):
+                self._compiled, dt = aot_compile(self._fn, *args)
             self.stats.compiles += 1
             self.stats.compile_ms += dt
-        colors, rounds, conf, total, nbytes = self._compiled(*args)
-        res = self._result(colors, rounds, conf, total, nbytes)
+        with TraceAnnotation(dispatch):
+            out = self._compiled(*args)
+        with TraceAnnotation(wait):
+            jax.block_until_ready(out)
+        with TraceAnnotation(fetch):
+            res = self._result(*out)
         self.stats.runs += 1
-        self.stats.last_run_ms = (time.perf_counter() - t0) * 1e3
         return res
 
-    def _result(self, colors, rounds, conf, total, nbytes) -> ColoringResult:
+    def _result(self, colors, rounds, conf, total, nbytes,
+                iters) -> ColoringResult:
         rounds = int(np.asarray(rounds).reshape(-1)[0])
         conf = int(np.asarray(conf).reshape(-1)[0])
         total = int(np.asarray(total).reshape(-1)[0])
@@ -635,6 +673,7 @@ class ColoringPlan:
             comm_bytes_total=int(by_round.sum()),
             comm_bytes_by_round=by_round.astype(np.int64),
             comm_bytes_by_level=by_level.astype(np.int64),
+            spec_iters=int(np.asarray(iters).max()),
         )
 
     # _gather_colors only needs .n_global / .vertex_gid; mimic the

@@ -109,8 +109,14 @@ def speculate(idx, color_tab, active, deg_tab, gid_tab, *,
     (``neighbor_index``); ``color_tab`` is the ``(n_tab,)`` table whose
     first ``N`` entries are the rows.  Same contract and results as
     ``core.local.local_color_d1`` (``idx = adj``) and ``local_color_d2``
-    (``idx`` = one- and two-hop, or two-hop only for pd2): returns the
-    updated table.
+    (``idx`` = one- and two-hop, or two-hop only for pd2): returns
+    ``(table, iters)``, the updated table and the number of
+    assign+resolve iterations the fixed point took.
+
+    Each piece runs under a ``jax.named_scope`` (``spec.invariant``,
+    ``spec.gather_assign``, ``spec.assign``, ``spec.gather_resolve``,
+    ``spec.resolve``), so a profile names its device operations by
+    layer whatever numbers XLA gives them.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -118,8 +124,9 @@ def speculate(idx, color_tab, active, deg_tab, gid_tab, *,
     color_tab = color_tab.astype(jnp.int32)
     t, n_pad, idx_t = _lane_layout(idx, color_tab.shape[0], tile)
     rest = color_tab[n:]                 # ghosts + pad slot: never written
-    nd = deg_tab.astype(jnp.int32)[idx_t]
-    ng = gid_tab.astype(jnp.int32)[idx_t]
+    with jax.named_scope("spec.invariant"):
+        nd = deg_tab.astype(jnp.int32)[idx_t]
+        ng = gid_tab.astype(jnp.int32)[idx_t]
     dv = pad_lanes(deg_tab[:n].astype(jnp.int32), n_pad)
     gv = pad_lanes(gid_tab[:n].astype(jnp.int32), n_pad)
     act = pad_lanes(active.astype(jnp.int32), n_pad)
@@ -134,16 +141,22 @@ def speculate(idx, color_tab, active, deg_tab, gid_tab, *,
 
     def body(st):
         colors, base, it = st
-        newc, base = assign_block(gather(colors), colors, base, act, **kw)
-        colors = _resolve_block(gather(newc), nd, ng, newc, dv, gv, act,
-                                recolor_degrees=recolor_degrees, **kw)
+        with jax.named_scope("spec.gather_assign"):
+            nbr = gather(colors)
+        with jax.named_scope("spec.assign"):
+            newc, base = assign_block(nbr, colors, base, act, **kw)
+        with jax.named_scope("spec.gather_resolve"):
+            nbr = gather(newc)
+        with jax.named_scope("spec.resolve"):
+            colors = _resolve_block(nbr, nd, ng, newc, dv, gv, act,
+                                    recolor_degrees=recolor_degrees, **kw)
         return colors, base, it + 1
 
     colors0 = pad_lanes(color_tab[:n], n_pad)
     base0 = jnp.ones((n_pad,), jnp.int32)
-    colors, _, _ = jax.lax.while_loop(cond, body,
-                                      (colors0, base0, jnp.int32(0)))
-    return jnp.concatenate([colors[:n], rest])
+    colors, _, iters = jax.lax.while_loop(cond, body,
+                                          (colors0, base0, jnp.int32(0)))
+    return jnp.concatenate([colors[:n], rest]), iters
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -168,11 +181,13 @@ def fused_round(
     """One fused inner round: detect → zero losers → speculative recolor.
 
     Returns ``(new_colors (N,), lose_v (N,) bool, lose_ghost (G,) bool,
-    n_conflicts scalar int32)`` — exactly the decomposed
-    ``_detect_part`` + ``_recolor_part`` composition of the reference
-    backend (``fused_round_ref`` is the pinned oracle).  Optional
-    ``(pair_slots, pair_colors)`` ghost updates (slots ``>= G`` drop) are
-    applied before detection.
+    n_conflicts scalar int32, iters scalar int32)`` — exactly the
+    decomposed ``_detect_part`` + ``_recolor_part`` composition of the
+    reference backend (``fused_round_ref`` is the pinned oracle); ``iters``
+    is the :func:`speculate` iteration count of the losers' recolor.
+    Optional ``(pair_slots, pair_colors)`` ghost updates (slots ``>= G``
+    drop) are applied before detection, which runs under the
+    ``round.detect`` named scope.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -194,19 +209,21 @@ def fused_round(
     # -- 1+2. Alg-4 owned-vs-ghost detection; ghost-side losers scattered.
     idx = neighbor_index(adj_cidx, two_hop_cidx, problem)
     t, n_pad, idx_t = _lane_layout(idx, n + g + 1, tile)
-    lose_v, lose_o, count = detect_block(
-        idx_t, tab[idx_t], deg_tab[idx_t], gid_tab[idx_t],
-        pad_lanes(colors, n_pad), pad_lanes(deg_tab[:n], n_pad),
-        pad_lanes(gid_tab[:n], n_pad),
-        pad_lanes(is_boundary.astype(jnp.int32), n_pad),
-        n_loc=n, n_tab=n + g, recolor_degrees=recolor_degrees,
-        tile=t, interpret=interpret)
+    with jax.named_scope("round.detect"):
+        lose_v, lose_o, count = detect_block(
+            idx_t, tab[idx_t], deg_tab[idx_t], gid_tab[idx_t],
+            pad_lanes(colors, n_pad), pad_lanes(deg_tab[:n], n_pad),
+            pad_lanes(gid_tab[:n], n_pad),
+            pad_lanes(is_boundary.astype(jnp.int32), n_pad),
+            n_loc=n, n_tab=n + g, recolor_degrees=recolor_degrees,
+            tile=t, interpret=interpret)
     lose_l = lose_v[:n] != 0
     lose_g = lose_table(idx_t, lose_o, n + g + 1)[n:n + g] != 0
 
     # -- 3. zero losers, speculate them to a fixed point. --------------
     tab = jnp.concatenate([jnp.where(lose_l, 0, colors), ghost, zero])
-    tab = speculate(idx, tab, lose_l, deg_tab, gid_tab,
-                    recolor_degrees=recolor_degrees, max_iters=max_iters,
-                    tile=tile, interpret=interpret)
-    return tab[:n], lose_l, lose_g, count.sum()
+    tab, iters = speculate(idx, tab, lose_l, deg_tab, gid_tab,
+                           recolor_degrees=recolor_degrees,
+                           max_iters=max_iters, tile=tile,
+                           interpret=interpret)
+    return tab[:n], lose_l, lose_g, count.sum(), iters

@@ -49,8 +49,9 @@ def local_color_d1_pallas(
     recolor_degrees: bool = True, max_iters: int = 512,
     interpret: bool | None = None, tile: int = DEFAULT_TILE,
 ):
-    """Kernel-backed distance-1 local coloring (same contract as core.local):
-    the ``fused_round.speculate`` fixed point over the one-hop rows."""
+    """Kernel-backed distance-1 local coloring (same contract as core.local,
+    ``(table, iters)``): the ``fused_round.speculate`` fixed point over the
+    one-hop rows."""
     return speculate(adj_cidx, color_tab, active, deg_tab, gid_tab,
                      recolor_degrees=recolor_degrees, max_iters=max_iters,
                      tile=tile, interpret=interpret)
@@ -89,7 +90,8 @@ def local_color_d2_pallas(
     partial_d2: bool = False, recolor_degrees: bool = True, max_iters: int = 1024,
     interpret: bool | None = None, tile: int = 128,
 ):
-    """Kernel-backed distance-2 local coloring (same contract as core.local).
+    """Kernel-backed distance-2 local coloring (same contract as core.local,
+    ``(table, iters)``).
 
     Assignment runs through the ``d2_forbidden`` net-based kernel; the
     speculative-collision resolution is the identical Alg-4 loser rule over
@@ -124,5 +126,6 @@ def local_color_d2_pallas(
         tab = tab.at[:n_loc].set(jnp.where(active & lose, 0, colors))
         return tab, base, it + 1
 
-    color_tab, _, _ = jax.lax.while_loop(cond, body, (color_tab, base0, jnp.int32(0)))
-    return color_tab
+    color_tab, _, iters = jax.lax.while_loop(
+        cond, body, (color_tab, base0, jnp.int32(0)))
+    return color_tab, iters

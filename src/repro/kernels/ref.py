@@ -94,9 +94,9 @@ def fused_round_ref(adj_cidx, colors, ghost, deg_tab, gid_tab, is_boundary,
                               else adj_cidx)
     kw = dict(problem=problem, recolor_degrees=recolor_degrees)
     lose_l, lose_g, conf = _detect_part(st, colors, ghost, **kw)
-    new_colors = _recolor_part(st, jnp.where(lose_l, 0, colors), ghost,
-                               lose_l, lose_g, **kw)
-    return new_colors, lose_l, lose_g, conf
+    new_colors, iters = _recolor_part(st, jnp.where(lose_l, 0, colors),
+                                      ghost, lose_l, lose_g, **kw)
+    return new_colors, lose_l, lose_g, conf, iters
 
 
 def flash_attention_ref(q, k, v, *, causal=True):

@@ -232,6 +232,7 @@ def main() -> None:
     print(f"[color] {res.problem} parts={res.n_parts} "
           f"backend={res.backend} exchange={res.exchange} "
           f"colors={res.n_colors} rounds={res.rounds} "
+          f"spec_iters={res.spec_iters} "
           f"conflicts={res.total_conflicts} proper={ok} "
           f"converged={res.converged} "
           f"comm/round={res.comm_bytes_per_round}B "

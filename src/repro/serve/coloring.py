@@ -466,7 +466,7 @@ class _SlotGroup:
         return self.plan._result(
             np.asarray(c["colors"][i]), np.asarray(c["rounds"][i]),
             np.asarray(c["conf"][i]), np.asarray(c["total"][i]),
-            np.asarray(c["bytes"][i]))
+            np.asarray(c["bytes"][i]), np.asarray(c["iters"][i]))
 
     # -- compiled programs -------------------------------------------------
 

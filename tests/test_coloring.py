@@ -124,9 +124,9 @@ def test_interior_never_recolored():
     detect = jax.vmap(partial(D._detect_part, problem="d1", recolor_degrees=True))
     sendbuf = jax.vmap(send_buffer)
     P_, G = st_np["ghost_part"].shape
-    colors = recolor(st, jnp.zeros((P_, pg.n_local), jnp.int32),
-                     jnp.zeros((P_, G), jnp.int32), st["active0"],
-                     jnp.zeros_like(st["ghost_real"]))
+    colors, _ = recolor(st, jnp.zeros((P_, pg.n_local), jnp.int32),
+                        jnp.zeros((P_, G), jnp.int32), st["active0"],
+                        jnp.zeros_like(st["ghost_real"]))
     interior = st_np["active0"] & ~st_np["is_boundary"]
     snapshot = np.asarray(colors)[interior]
     for _ in range(4):
@@ -135,7 +135,7 @@ def test_interior_never_recolored():
                           allbuf[st["ghost_part"], st["ghost_slot"]], 0)
         lose, lose_g, _ = detect(st, colors, ghost)
         colors = jnp.where(lose, 0, colors)
-        colors = recolor(st, colors, ghost, lose, lose_g)
+        colors, _ = recolor(st, colors, ghost, lose, lose_g)
     assert (np.asarray(colors)[interior] == snapshot).all()
 
 

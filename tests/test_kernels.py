@@ -137,9 +137,9 @@ def test_pallas_local_color_matches_core():
     args = (jnp.asarray(st_["adj_cidx"][0]), tab0,
             jnp.asarray(st_["active0"][0]), jnp.asarray(st_["deg_tab"][0]),
             jnp.asarray(st_["gid_tab"][0]))
-    a = local_color_d1(*args)
-    b = ops.local_color_d1_pallas(*args)
+    (a, ia), (b, ib) = local_color_d1(*args), ops.local_color_d1_pallas(*args)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(ia) == int(ib) > 0
 
 
 def test_pallas_local_color_d2_matches_core():
@@ -154,16 +154,17 @@ def test_pallas_local_color_d2_matches_core():
     nl, gh = pg.n_local, pg.n_ghost
     for partial_d2 in (False, True):
         tab0 = jnp.zeros(nl + gh + 1, jnp.int32)
-        a = local_color_d2(
+        a, ia = local_color_d2(
             jnp.asarray(st_["adj_cidx"][0]), jnp.asarray(st_["two_hop_cidx"][0]),
             tab0, jnp.asarray(st_["active0"][0]), jnp.asarray(st_["deg_tab"][0]),
             jnp.asarray(st_["gid_tab"][0]), partial_d2=partial_d2)
-        b = ops.local_color_d2_pallas(
+        b, ib = ops.local_color_d2_pallas(
             jnp.asarray(st_["adj_cidx"][0]), jnp.asarray(st_["two_hop_cidx"][0]),
             jnp.asarray(st_["ext_adj_cidx"][0]), tab0,
             jnp.asarray(st_["active0"][0]), jnp.asarray(st_["deg_tab"][0]),
             jnp.asarray(st_["gid_tab"][0]), partial_d2=partial_d2)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert int(ia) == int(ib) > 0
 
 
 # ---------------------------------------------------------------------------
@@ -203,7 +204,9 @@ def _fused_vs_ref(s, problem, tile, pair_slots=None, pair_colors=None):
         s["is_boundary"], two_hop_cidx=th, pair_slots=pair_slots,
         pair_colors=pair_colors, ext_adj_cidx=s.get("ext_adj_cidx"),
         problem=problem)
-    for g_, w_, name in zip(got, want, ("colors", "lose_l", "lose_g", "conf")):
+    names = ("colors", "lose_l", "lose_g", "conf", "iters")
+    assert len(got) == len(want) == len(names)
+    for g_, w_, name in zip(got, want, names):
         np.testing.assert_array_equal(np.asarray(g_), np.asarray(w_),
                                       err_msg=f"{problem}/{name}")
 
@@ -264,6 +267,7 @@ def test_fused_backend_round_property_d1(seed, parts):
     kw = dict(problem="d1", recolor_degrees=True)
     got = PallasFusedBackend(interpret=True).round(s, colors, ghost, **kw)
     want = ReferenceBackend().round(s, colors, ghost, **kw)
+    assert len(got) == len(want) == 5
     for g_, w_ in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g_), np.asarray(w_))
 

@@ -39,6 +39,7 @@ for problem in ("d1", "d1_2gl", "d2"):
     assert sim.converged and smap.converged, problem
     assert (sim.colors == smap.colors).all(), problem
     assert sim.rounds == smap.rounds, problem
+    assert sim.spec_iters == smap.spec_iters > 0, problem
 halo = color_distributed(pg, problem="d1", engine="shard_map", exchange="halo")
 ag = color_distributed(pg, problem="d1", engine="shard_map")
 assert (halo.colors == ag.colors).all()
@@ -75,6 +76,7 @@ for backend in ("reference", "pallas", "pallas_fused"):
         assert res.converged, (backend, exchange)
         assert (res.colors == ref.colors).all(), (backend, exchange)
         assert res.rounds == ref.rounds, (backend, exchange)
+        assert res.spec_iters == ref.spec_iters, (backend, exchange)
 assert is_proper_d1(g, ref.colors)
 
 # Measured accounting: delta < all_gather per round after round 1, and
@@ -324,6 +326,7 @@ for (pg, req), res in zip(pairs, results):
     assert (res.colors == solo.colors).all()
     assert (res.colors == sim.colors).all()
     assert res.rounds == solo.rounds == sim.rounds
+    assert res.spec_iters == solo.spec_iters == sim.spec_iters
     assert list(res.comm_bytes_by_round) == list(sim.comm_bytes_by_round)
 assert is_proper_d1(g1, results[0].colors)
 print("OK")

@@ -6,6 +6,9 @@ tables, exchange prepare, traced program — is built once per
 max_rounds)``; ``plan.run()`` performs zero host-side state rebuilds and
 zero retraces, and is bit-identical to a cold ``color_distributed``.
 """
+import glob
+import re
+
 import numpy as np
 import pytest
 
@@ -337,6 +340,7 @@ def test_service_batch_bit_identical_to_solo():
             solo = svc.plan.run(color_mask=m)
             assert (b.colors == solo.colors).all()
             assert b.rounds == solo.rounds
+            assert b.spec_iters == solo.spec_iters
             assert b.total_conflicts == solo.total_conflicts
             assert list(b.comm_bytes_by_round) == list(solo.comm_bytes_by_round)
     assert svc.buckets == [4, 8]                      # bucketed, not per-size
@@ -388,3 +392,67 @@ def test_service_rejects_unknown_request_keys():
         svc.run_batch([{"mask": None}, {}])           # typo for color_mask
     with pytest.raises(TypeError, match="unknown request keys"):
         svc.run_batch([{"color_mask": None, "seeds": 1}])
+
+
+# ---------------------------------------------------------------------------
+# Tracing: host spans, named device scopes, the speculation counter.
+# ---------------------------------------------------------------------------
+
+def test_plan_run_emits_its_host_spans_in_order(tmp_path):
+    """Each run passes the ``plan.*`` spans in order; ``plan.compile``
+    only on the run that compiles."""
+    import jax
+    from jax.profiler import ProfileData
+
+    plan = build_plan(PG, problem="d1", exchange="sparse_delta",
+                      engine="simulate")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        plan.run()
+        plan.run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = sorted((e.start_ns, e.name)
+                   for plane in ProfileData.from_file(path).planes
+                   for line in plane.lines for e in line.events
+                   if e.name in plan_mod.PLAN_SPANS)
+    cold = list(plan_mod.PLAN_SPANS)
+    warm = [s for s in cold if s != "plan.compile"]
+    assert [name for _, name in spans] == cold + warm
+
+
+# The scopes the loop program names its device work by (``_make_loop``,
+# ``kernels.fused_round``).
+SCOPES = ("spec.invariant", "spec.gather_assign", "spec.assign",
+          "spec.gather_resolve", "spec.resolve", "round", "round.detect",
+          "exchange")
+
+
+@pytest.mark.parametrize("problem", ["d1", "d2"])
+def test_named_scopes_land_on_compiled_instructions(problem):
+    plan = build_plan(PG, problem=problem, backend="pallas_fused",
+                      exchange="sparse_delta", engine="simulate")
+    plan.run()
+    op_names = re.findall(r'op_name="([^"]*)"', plan.executable.as_text())
+    scopes = {part for name in op_names for part in name.split("/")}
+    assert set(SCOPES) <= scopes
+
+
+@pytest.mark.parametrize("problem", ["d1", "d2", "pd2"])
+def test_spec_iters_counted_alike_by_both_backends(problem):
+    """The reference backend counts its own loop; its math is the fused
+    kernels', so the counts agree.  A request with an empty mask runs no
+    iteration."""
+    results = {}
+    for backend in ("reference", "pallas_fused"):
+        plan = build_plan(PG, problem=problem, backend=backend,
+                          exchange="sparse_delta", engine="simulate")
+        full = plan.run()
+        empty = plan.run(color_mask=np.zeros(GRAPH.n, bool),
+                         colors0=full.colors)
+        assert empty.spec_iters == 0 and (empty.colors == full.colors).all()
+        results[backend] = full
+    ref, fused = results["reference"], results["pallas_fused"]
+    assert (ref.colors == fused.colors).all()
+    assert ref.spec_iters == fused.spec_iters > 0

@@ -139,3 +139,46 @@ def test_simulate_plan_loop_compiles(spec):
     txt = compiled_text(jax.jit(plan.raw_fn, donate_argnums=(1,)), st,
                         *inputs)
     assert "tpu_custom_call" in txt
+
+
+def test_shard_map_plan_loop_compiles_on_four_chips(topo):
+    """The four-chip cell's loop program (``shard_map`` over a 2x2 mesh,
+    ``sparse_delta``'s ``ppermute`` phases, the per-part iteration count
+    as a sharded output), at a small mesh."""
+    import copy
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as PS
+
+    from repro.core.backend import PallasFusedBackend
+    from repro.core.exchange import get_exchange
+    from repro.core.plan import (PlanStats, _build_shard_map_fn,
+                                 cached_device_state)
+    from repro.graph.partition import partition_graph
+    from repro.launch.color import make_graph
+
+    pg = partition_graph(make_graph("hex:64,32,32"), 4)
+    strategy = copy.copy(get_exchange("sparse_delta"))
+    st_np = dict(cached_device_state(pg, "d1"))
+    active0 = st_np.pop("active0")
+    st_np.update(strategy.prepare(pg, st_np))
+    mesh = Mesh(np.array(topo.devices), ("p",), axis_types=(AxisType.Auto,))
+    _, fn = _build_shard_map_fn(
+        strategy, PallasFusedBackend(interpret=False), problem="d1",
+        recolor_degrees=True, max_rounds=64, n_parts=4, mesh=mesh,
+        st_keys=list(st_np), stats=PlanStats())
+    part, rep = NamedSharding(mesh, PS("p")), NamedSharding(mesh, PS())
+
+    def shape(x, sharding=part):
+        x = np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    colors0 = np.zeros((4, pg.n_local), np.int32)
+    ghost0 = np.zeros(pg.ghost_gid.shape, np.int32)
+    compiled = fn.lower({k: shape(v) for k, v in st_np.items()},
+                        shape(colors0), shape(ghost0), shape(active0),
+                        shape(np.int32(0), rep)).compile()
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt and "collective-permute" in txt
+    assert compiled.output_shardings[-1].spec == PS("p")   # iters per part
