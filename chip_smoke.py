@@ -113,6 +113,8 @@ def timed_plan(phase, dev, pg, *, backend, exchange, engine="simulate",
            compile_ms=f"{plan.stats.compile_ms:.1f}",
            run_ms=f"{run_ms - plan.stats.compile_ms:.1f}",
            rounds=res.rounds, spec_iters=res.spec_iters, colors=res.n_colors,
+           diagonals=plan.stats.diagonals,
+           diagonal_share=f"{plan.stats.diagonal_share:.4f}",
            comm_bytes_total=res.comm_bytes_total,
            peak_bytes_in_use=peak_bytes(dev))
     return plan, res

@@ -50,17 +50,28 @@ class LocalBackend:
 
     name: str = "abstract"
 
+    def reads_diagonals(self, problem: str) -> bool:
+        """Whether ``problem``'s neighbor blocks are read through
+        ``kernels.diagonals.read_neighbors``: a plan then looks for the
+        diagonal layout of the problem's neighbor index and hands it in
+        as ``diag`` (the state's ``nbr_diag``)."""
+        return False
+
     def color_d1(self, adj_cidx, color_tab, active, deg_tab, gid_tab, *,
-                 recolor_degrees: bool):
+                 recolor_degrees: bool, diag=None):
         """Distance-1 speculative coloring of ``active`` rows; returns
         ``(color_table, iters)``: the updated table and the number of
-        speculative iterations its fixed point took."""
+        speculative iterations its fixed point took.  ``diag`` is the
+        diagonal layout of ``adj_cidx`` when the plan found one; it never
+        changes the result."""
         raise NotImplementedError
 
     def color_d2(self, adj_cidx, two_hop_cidx, ext_adj_cidx, color_tab, active,
-                 deg_tab, gid_tab, *, partial_d2: bool, recolor_degrees: bool):
+                 deg_tab, gid_tab, *, partial_d2: bool, recolor_degrees: bool,
+                 diag=None):
         """Distance-2 / partial-distance-2 speculative coloring; returns
-        ``(color_table, iters)`` like :meth:`color_d1`."""
+        ``(color_table, iters)`` like :meth:`color_d1` (``diag``: the
+        layout of the one- plus two-hop index, two-hop only for pd2)."""
         raise NotImplementedError
 
     def detect(self, adj_cidx, colors_loc, color_tab, deg_tab, gid_tab,
@@ -106,12 +117,12 @@ class ReferenceBackend(LocalBackend):
     name = "reference"
 
     def color_d1(self, adj_cidx, color_tab, active, deg_tab, gid_tab, *,
-                 recolor_degrees):
+                 recolor_degrees, diag=None):
         return local_color_d1(adj_cidx, color_tab, active, deg_tab, gid_tab,
                               recolor_degrees=recolor_degrees)
 
     def color_d2(self, adj_cidx, two_hop_cidx, ext_adj_cidx, color_tab, active,
-                 deg_tab, gid_tab, *, partial_d2, recolor_degrees):
+                 deg_tab, gid_tab, *, partial_d2, recolor_degrees, diag=None):
         return local_color_d2(adj_cidx, two_hop_cidx, color_tab, active,
                               deg_tab, gid_tab, partial_d2=partial_d2,
                               recolor_degrees=recolor_degrees)
@@ -155,18 +166,21 @@ class PallasBackend(LocalBackend):
         self.tile_d1 = tile_d1
         self.tile_d2 = tile_d2
 
+    def reads_diagonals(self, problem):
+        return problem in ("d1", "d1_2gl")      # d2 is d2_forbidden's
+
     def color_d1(self, adj_cidx, color_tab, active, deg_tab, gid_tab, *,
-                 recolor_degrees):
+                 recolor_degrees, diag=None):
         from repro.kernels.ops import local_color_d1_pallas
 
         return local_color_d1_pallas(
-            adj_cidx, color_tab, active, deg_tab, gid_tab,
+            adj_cidx, color_tab, active, deg_tab, gid_tab, diag=diag,
             recolor_degrees=recolor_degrees,
             interpret=self.interpret, tile=self.tile_d1,
         )
 
     def color_d2(self, adj_cidx, two_hop_cidx, ext_adj_cidx, color_tab, active,
-                 deg_tab, gid_tab, *, partial_d2, recolor_degrees):
+                 deg_tab, gid_tab, *, partial_d2, recolor_degrees, diag=None):
         from repro.kernels.ops import local_color_d2_pallas
 
         return local_color_d2_pallas(
@@ -215,13 +229,16 @@ class PallasFusedBackend(PallasBackend):
 
         self.tile_round = tile_round or DEFAULT_TILE
 
+    def reads_diagonals(self, problem):
+        return True
+
     def color_d2(self, adj_cidx, two_hop_cidx, ext_adj_cidx, color_tab, active,
-                 deg_tab, gid_tab, *, partial_d2, recolor_degrees):
+                 deg_tab, gid_tab, *, partial_d2, recolor_degrees, diag=None):
         from repro.kernels.fused_round import neighbor_index, speculate
 
         idx = neighbor_index(adj_cidx, two_hop_cidx,
                              "pd2" if partial_d2 else "d2")
-        return speculate(idx, color_tab, active, deg_tab, gid_tab,
+        return speculate(idx, color_tab, active, deg_tab, gid_tab, diag=diag,
                          recolor_degrees=recolor_degrees, max_iters=1024,
                          tile=self.tile_round, interpret=self.interpret)
 
@@ -238,8 +255,9 @@ class PallasFusedBackend(PallasBackend):
             st["gid_tab"], st["is_boundary"],
             two_hop_cidx=(st["two_hop_cidx"] if problem in ("d2", "pd2")
                           else None),
-            problem=problem, recolor_degrees=recolor_degrees,
-            tile=self.tile_round, interpret=self.interpret,
+            diag=st.get("nbr_diag"), problem=problem,
+            recolor_degrees=recolor_degrees, tile=self.tile_round,
+            interpret=self.interpret,
         )
 
 

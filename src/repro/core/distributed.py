@@ -47,6 +47,7 @@ __all__ = [
     "color_distributed",
     "color_single_device",
     "build_device_state",
+    "neighbor_diagonals",
 ]
 
 PROBLEMS = ("d1", "d1_2gl", "d2", "pd2")
@@ -153,6 +154,24 @@ def build_device_state(pg: PartitionedGraph, problem: str) -> dict[str, np.ndarr
     return state
 
 
+def neighbor_diagonals(st: dict[str, np.ndarray], problem: str):
+    """Diagonal layout of the neighbor index ``problem``'s speculative
+    coloring reads, over the stacked host state ``st``.
+
+    Returns ``kernels.diagonals.find_diagonals``' ``(layout or None,
+    share)``; the plan hands the layout to the backend as ``nbr_diag``.
+    """
+    from repro.kernels.diagonals import find_diagonals
+    from repro.kernels.fused_round import neighbor_index
+
+    n_tab = st["deg_tab"].shape[-1]
+    if problem == "d1_2gl":
+        idx = st["ext_adj_cidx"][:, : n_tab - 1]
+    else:
+        idx = neighbor_index(st["adj_cidx"], st.get("two_hop_cidx"), problem)
+    return find_diagonals(idx, n_tab)
+
+
 # ---------------------------------------------------------------------------
 # Per-part step functions (pure; no collectives; backend-pluggable).
 # ---------------------------------------------------------------------------
@@ -166,11 +185,13 @@ def _recolor_part(st, colors_loc, ghost_colors, active_loc, active_ghost, *,
     n_loc = colors_loc.shape[0]
     zero = jnp.zeros((1,), jnp.int32)
     color_tab = jnp.concatenate([colors_loc, ghost_colors, zero])
+    diag = st.get("nbr_diag")
     if problem in ("d2", "pd2"):
         color_tab, iters = backend.color_d2(
             st["adj_cidx"], st["two_hop_cidx"], st["ext_adj_cidx"],
             color_tab, active_loc, st["deg_tab"], st["gid_tab"],
             partial_d2=(problem == "pd2"), recolor_degrees=recolor_degrees,
+            diag=diag,
         )
         return color_tab[:n_loc], iters
     if problem == "d1_2gl":
@@ -185,12 +206,13 @@ def _recolor_part(st, colors_loc, ghost_colors, active_loc, active_ghost, *,
         tab, iters = backend.color_d1(
             st["ext_adj_cidx"][: n_loc + n_ghost], tab, active_ext,
             st["deg_tab"], st["gid_tab"], recolor_degrees=recolor_degrees,
+            diag=diag,
         )
         return tab[:n_loc], iters
     # plain d1
     color_tab, iters = backend.color_d1(
         st["adj_cidx"], color_tab, active_loc, st["deg_tab"], st["gid_tab"],
-        recolor_degrees=recolor_degrees,
+        recolor_degrees=recolor_degrees, diag=diag,
     )
     return color_tab[:n_loc], iters
 

@@ -51,6 +51,7 @@ from repro.core.distributed import (
     _recolor_part,
     _round_part,
     build_device_state,
+    neighbor_diagonals,
 )
 from repro.core.exchange import ExchangeStrategy, get_exchange, level_split
 from repro.core.validate import num_colors
@@ -101,6 +102,11 @@ class PlanStats:
     build_ms: float = 0.0       # host-side static-half cost (state + prepare)
     compiles: int = 0           # ahead-of-time lower+compile events
     compile_ms: float = 0.0     # total time spent tracing + compiling
+    # The neighbor reads' path: distinct diagonals the blocks are read
+    # along (``kernels.diagonals``) and the share of real neighbor entries
+    # they serve; 0 and 0.0 where the plan keeps the scalar gather.
+    diagonals: int = 0
+    diagonal_share: float = 0.0
 
 
 # --------------------------------------------------------------------------
@@ -239,7 +245,7 @@ def _build_shard_map_step(strategy: ExchangeStrategy, backend: LocalBackend, *,
 
     def device_step(st, carry):
         stats.traces += 1       # python side effect: fires only at trace time
-        st1 = {k: v[0] for k, v in st.items()}          # strip part axis
+        st1 = jax.tree_util.tree_map(lambda v: v[0], st)   # strip part axis
 
         def one(c):
             fresh = c["rounds"] < 0
@@ -322,7 +328,7 @@ def _build_shard_map_fn(strategy: ExchangeStrategy, backend: LocalBackend, *,
     def device_fn(st, c, g0, a0, seed):
         stats.traces += 1
         del seed
-        st = {k: v[0] for k, v in st.items()}           # strip part axis
+        st = jax.tree_util.tree_map(lambda v: v[0], st)    # strip part axis
         loop = _make_loop(
             partial(_recolor_part, st, **step_kw),
             partial(_round_part, st, **step_kw),
@@ -391,6 +397,12 @@ class ColoringPlan:
         # recoloring service varies (color_mask), so it must not be baked
         # into the compiled program.
         self._active0 = st_np.pop("active0")
+        if backend.reads_diagonals(key.problem):
+            diag, share = neighbor_diagonals(st_np, key.problem)
+            if diag is not None:
+                st_np["nbr_diag"] = diag
+                self.stats.diagonals = len(diag.offsets)
+                self.stats.diagonal_share = share
         st_np.update(strategy.prepare(pg, st_np))
 
         kw = dict(problem=key.problem, recolor_degrees=key.recolor_degrees,
@@ -420,7 +432,7 @@ class ColoringPlan:
             self._st = jax.device_put(
                 st_np, NamedSharding(mesh, PartitionSpec("p")))
         else:
-            self._st = {k: jnp.asarray(v) for k, v in st_np.items()}
+            self._st = jax.tree_util.tree_map(jnp.asarray, st_np)
             self.raw_fn = _build_simulate_fn(strategy, backend, **kw)
             self.raw_step = _build_simulate_step(strategy, backend, **kw)
             # The device-resident tables are the first argument; per-run
@@ -690,7 +702,7 @@ class ColoringPlan:
         gather tables; the compiled executable itself is not counted (XLA
         does not expose it portably), so treat this as a lower bound.
         """
-        st = sum(int(v.nbytes) for v in self._st.values())
+        st = sum(int(v.nbytes) for v in jax.tree_util.tree_leaves(self._st))
         host = sum(int(a.nbytes) for a in
                    (self._active0, self._gids, self._ghost_gids,
                     self._real, self._ghost_real, self._vertex_gid))

@@ -10,10 +10,13 @@ paper optimizes on GPU, so they get TPU kernels here:
 * ``scatter``     -- (slot, value) pair application for sparse exchanges
 * ``fused_round`` -- one whole detect→recolor round (gathers in XLA,
   dense lane-major kernels for detect / assign / resolve)
+* ``diagonals``   -- neighbor blocks read along the index's diagonals
+  (shifted table windows), the fused round's reads on structured meshes
 
 Mosaic has no in-kernel gather by a 2-D index, so every kernel consumes
-*dense* neighbor blocks: the wrapper gathers ``table[idx.T]`` in XLA into a
-lane-major ``(K, N)`` array (neighbor slot on sublanes, vertex on lanes)
+*dense* neighbor blocks: the wrapper reads ``table[idx.T]`` (XLA's gather,
+or along the diagonals) into a lane-major ``(K, N)`` array (neighbor slot
+on sublanes, vertex on lanes)
 and the kernel reduces over the ``K`` rows of a ``(K, tile)`` block.  Row
 vectors travel as ``(1, N)``.  Each kernel ships ``<name>.py``
 (``pl.pallas_call`` + ``BlockSpec`` grid), a jit'd wrapper re-exported by

@@ -4,23 +4,27 @@ The decomposed round (``core.distributed._detect_part`` then
 ``_recolor_part``) is Alg-4 conflict detection against the freshly
 exchanged ghosts, zeroing of the losers, and speculative recoloring of the
 losers to a fixed point.  This module runs the same round with the
-elementwise work in three Mosaic kernels and the irregular memory traffic
-in XLA, because Mosaic cannot gather from a table by a 2-D index nor
-scatter inside a kernel:
+elementwise work in three Mosaic kernels and the neighbor reads either in
+a fourth or in XLA, because Mosaic cannot gather from a table by a 2-D
+index nor scatter inside a kernel:
 
-  1. XLA gathers neighbor colors / degrees / gids into lane-major
+  1. XLA reads neighbor colors / degrees / gids into lane-major
      ``(K, N)`` blocks (``K`` = the ELL slots the problem reads: ``W`` for
      d1, ``W + W²`` for d2, ``W²`` for pd2 — one concatenated index block
      covers both the one- and two-hop sweeps) and the ``detect`` kernel
-     (``conflict.detect_block``) applies the Alg-4 loser rule;
+     (``conflict.detect_block``) applies the Alg-4 loser rule.  Every
+     block read is ``kernels.diagonals.read_neighbors``: a Mosaic kernel
+     that reads the table along the index's diagonals where the plan
+     found a diagonal layout (structured meshes), else XLA's scalar
+     gather;
   2. XLA scatter-maxes the per-edge neighbor-side lose flags into the
      ghost lose table;
-  3. :func:`speculate` iterates, to a fixed point, an XLA color gather,
+  3. :func:`speculate` iterates, to a fixed point, a neighbor-color read,
      the ``assign`` kernel (``vb_bit.assign_block``: windowed forbidden
-     mask + lowest clear bit), a second gather of the updated table, and
+     mask + lowest clear bit), a second read of the updated table, and
      the ``resolve`` kernel (intra-part Alg-4 collisions; losers zeroed).
 
-The degree/gid gathers are loop invariant and paid once per call.  The
+The degree/gid reads are loop invariant and paid once per call.  The
 kernels run a 1-D grid over ``tile``-lane row blocks
 (``kernels.lane_tile``), so VMEM holds one ``(K, tile)`` block per operand
 at any shard size; tables live in HBM.  The math is the jnp reference's
@@ -35,12 +39,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.conflict import lose_table, v_loses
 from repro.kernels import (default_interpret, block_spec, lane_tile,
                            pad_lanes, row_spec)
 from repro.kernels.conflict import detect_block
+from repro.kernels.diagonals import read_neighbors
 from repro.kernels.vb_bit import assign_block
 
 DEFAULT_TILE = 2048
@@ -49,14 +55,16 @@ __all__ = ["fused_round", "speculate", "neighbor_index", "DEFAULT_TILE"]
 
 
 def neighbor_index(adj_cidx, two_hop_cidx, problem: str):
-    """The ``(N, K)`` color-table indices ``problem`` reads per vertex."""
+    """The ``(..., N, K)`` color-table indices ``problem`` reads per vertex
+    (host arrays stay host arrays)."""
     if problem == "d1":
         return adj_cidx
     if two_hop_cidx is None:
         raise ValueError(f"problem={problem!r} requires two_hop_cidx")
     if problem == "pd2":
         return two_hop_cidx
-    return jnp.concatenate([adj_cidx, two_hop_cidx], axis=1)
+    xp = np if isinstance(adj_cidx, np.ndarray) else jnp
+    return xp.concatenate([adj_cidx, two_hop_cidx], axis=-1)
 
 
 def _resolve_kernel(recolor_degrees, nc_ref, nd_ref, ng_ref,
@@ -100,7 +108,7 @@ def _lane_layout(idx, n_tab, tile):
 
 @functools.partial(jax.jit, static_argnames=(
     "recolor_degrees", "max_iters", "tile", "interpret"))
-def speculate(idx, color_tab, active, deg_tab, gid_tab, *,
+def speculate(idx, color_tab, active, deg_tab, gid_tab, *, diag=None,
               recolor_degrees: bool = True, max_iters: int = 512,
               tile: int = DEFAULT_TILE, interpret: bool | None = None):
     """Speculative local coloring of ``active`` rows to a fixed point.
@@ -111,7 +119,9 @@ def speculate(idx, color_tab, active, deg_tab, gid_tab, *,
     ``core.local.local_color_d1`` (``idx = adj``) and ``local_color_d2``
     (``idx`` = one- and two-hop, or two-hop only for pd2): returns
     ``(table, iters)``, the updated table and the number of
-    assign+resolve iterations the fixed point took.
+    assign+resolve iterations the fixed point took.  ``diag`` is the
+    diagonal layout of ``idx`` (``kernels.diagonals.find_diagonals``) when
+    it has one: every neighbor block is then read along it.
 
     Each piece runs under a ``jax.named_scope`` (``spec.invariant``,
     ``spec.gather_assign``, ``spec.assign``, ``spec.gather_resolve``,
@@ -120,20 +130,33 @@ def speculate(idx, color_tab, active, deg_tab, gid_tab, *,
     """
     if interpret is None:
         interpret = default_interpret()
-    n = active.shape[0]
-    color_tab = color_tab.astype(jnp.int32)
     t, n_pad, idx_t = _lane_layout(idx, color_tab.shape[0], tile)
-    rest = color_tab[n:]                 # ghosts + pad slot: never written
     with jax.named_scope("spec.invariant"):
-        nd = deg_tab.astype(jnp.int32)[idx_t]
-        ng = gid_tab.astype(jnp.int32)[idx_t]
+        nd, ng = read_neighbors(idx_t, diag, deg_tab.astype(jnp.int32),
+                                gid_tab.astype(jnp.int32), tile=t,
+                                interpret=interpret)
+    return _speculate(idx_t, diag, nd, ng, color_tab, active, deg_tab,
+                      gid_tab, recolor_degrees=recolor_degrees,
+                      max_iters=max_iters, tile=t, interpret=interpret)
+
+
+def _speculate(idx_t, diag, nd, ng, color_tab, active, deg_tab, gid_tab, *,
+               recolor_degrees, max_iters, tile, interpret):
+    """:func:`speculate`'s fixed point over the laid-out index ``idx_t``
+    and its loop-invariant degree / gid blocks ``nd`` / ``ng``."""
+    n = active.shape[0]
+    n_pad = idx_t.shape[1]
+    color_tab = color_tab.astype(jnp.int32)
+    rest = color_tab[n:]                 # ghosts + pad slot: never written
     dv = pad_lanes(deg_tab[:n].astype(jnp.int32), n_pad)
     gv = pad_lanes(gid_tab[:n].astype(jnp.int32), n_pad)
     act = pad_lanes(active.astype(jnp.int32), n_pad)
-    kw = dict(tile=t, interpret=interpret)
+    kw = dict(tile=tile, interpret=interpret)
 
     def gather(colors):
-        return jnp.concatenate([colors[:n], rest])[idx_t]
+        nbr, = read_neighbors(idx_t, diag,
+                              jnp.concatenate([colors[:n], rest]), **kw)
+        return nbr
 
     def cond(st):
         colors, _, it = st
@@ -172,6 +195,7 @@ def fused_round(
     pair_slots: jnp.ndarray | None = None,     # (C,) optional ghost updates
     pair_colors: jnp.ndarray | None = None,    # (C,)
     *,
+    diag=None,                                 # Diagonals of the index
     problem: str = "d1",
     recolor_degrees: bool = True,
     max_iters: int | None = None,
@@ -187,7 +211,8 @@ def fused_round(
     is the :func:`speculate` iteration count of the losers' recolor.
     Optional ``(pair_slots, pair_colors)`` ghost updates (slots ``>= G``
     drop) are applied before detection, which runs under the
-    ``round.detect`` named scope.
+    ``round.detect`` named scope.  ``diag`` is the diagonal layout of the
+    problem's neighbor index (:func:`neighbor_index`), when it has one.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -210,8 +235,10 @@ def fused_round(
     idx = neighbor_index(adj_cidx, two_hop_cidx, problem)
     t, n_pad, idx_t = _lane_layout(idx, n + g + 1, tile)
     with jax.named_scope("round.detect"):
+        nbr, nd, ng = read_neighbors(idx_t, diag, tab, deg_tab, gid_tab,
+                                     tile=t, interpret=interpret)
         lose_v, lose_o, count = detect_block(
-            idx_t, tab[idx_t], deg_tab[idx_t], gid_tab[idx_t],
+            idx_t, nbr, nd, ng,
             pad_lanes(colors, n_pad), pad_lanes(deg_tab[:n], n_pad),
             pad_lanes(gid_tab[:n], n_pad),
             pad_lanes(is_boundary.astype(jnp.int32), n_pad),
@@ -220,10 +247,11 @@ def fused_round(
     lose_l = lose_v[:n] != 0
     lose_g = lose_table(idx_t, lose_o, n + g + 1)[n:n + g] != 0
 
-    # -- 3. zero losers, speculate them to a fixed point. --------------
+    # -- 3. zero losers, speculate them to a fixed point (the degree and
+    # gid blocks are detection's). --------------------------------------
     tab = jnp.concatenate([jnp.where(lose_l, 0, colors), ghost, zero])
-    tab, iters = speculate(idx, tab, lose_l, deg_tab, gid_tab,
-                           recolor_degrees=recolor_degrees,
-                           max_iters=max_iters, tile=tile,
-                           interpret=interpret)
+    tab, iters = _speculate(idx_t, diag, nd, ng, tab, lose_l, deg_tab,
+                            gid_tab, recolor_degrees=recolor_degrees,
+                            max_iters=max_iters, tile=t,
+                            interpret=interpret)
     return tab[:n], lose_l, lose_g, count.sum(), iters

@@ -45,14 +45,14 @@ __all__ = [
 
 
 def local_color_d1_pallas(
-    adj_cidx, color_tab, active, deg_tab, gid_tab, *,
+    adj_cidx, color_tab, active, deg_tab, gid_tab, *, diag=None,
     recolor_degrees: bool = True, max_iters: int = 512,
     interpret: bool | None = None, tile: int = DEFAULT_TILE,
 ):
     """Kernel-backed distance-1 local coloring (same contract as core.local,
     ``(table, iters)``): the ``fused_round.speculate`` fixed point over the
-    one-hop rows."""
-    return speculate(adj_cidx, color_tab, active, deg_tab, gid_tab,
+    one-hop rows, read along ``diag`` when given."""
+    return speculate(adj_cidx, color_tab, active, deg_tab, gid_tab, diag=diag,
                      recolor_degrees=recolor_degrees, max_iters=max_iters,
                      tile=tile, interpret=interpret)
 

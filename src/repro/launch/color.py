@@ -47,6 +47,7 @@ from repro.core.backend import list_backends
 from repro.core.baseline import color_baseline
 from repro.core.distributed import color_distributed
 from repro.core.exchange import list_exchanges
+from repro.core.plan import get_plan
 from repro.core.reduce import list_orders
 from repro.core.validate import is_proper_d1, is_proper_d2, is_proper_pd2
 from repro.graph import generators as gen
@@ -185,6 +186,7 @@ def main() -> None:
           f"maxdeg={g.max_degree}")
     pg = make_partition(g, args)
     t0 = time.time()
+    plan = None                     # the plan that colored, for its stats
     if args.baseline:
         if args.backend != "reference" or args.exchange != "all_gather":
             print("[color] note: --baseline uses the reference backend and "
@@ -201,16 +203,19 @@ def main() -> None:
             reduce_passes=args.reduce_passes, reduce_order=args.reduce_order)
         for _ in range(args.repeat):
             res = svc.submit()
+        plan = svc.plan
         print(f"[color] repeat={args.repeat} engine={svc.engine} "
               f"compile_ms={svc.stats.cold_ms:.1f} "
               f"({svc.stats.cold_runs} programs, paid once) "
               f"warm_ms={svc.stats.warm_ms_mean:.2f} "
               f"(mean execution of {svc.stats.warm_requests} timesteps)")
     else:
-        res = color_distributed(
-            pg, problem=args.problem,
-            recolor_degrees=not args.no_recolor_degrees,
-            backend=args.backend, exchange=args.exchange, engine=args.engine)
+        kw = dict(problem=args.problem,
+                  recolor_degrees=not args.no_recolor_degrees,
+                  backend=args.backend, exchange=args.exchange,
+                  engine=args.engine)
+        res = color_distributed(pg, **kw)
+        plan = get_plan(pg, **kw)   # the cached plan that just ran
     if args.reduce_passes > 0 and (args.baseline or args.repeat <= 1):
         from repro.core.quality import trajectory
         from repro.core.reduce import reduce_colors
@@ -233,6 +238,9 @@ def main() -> None:
           f"backend={res.backend} exchange={res.exchange} "
           f"colors={res.n_colors} rounds={res.rounds} "
           f"spec_iters={res.spec_iters} "
+          + (f"diagonals={plan.stats.diagonals} "
+             f"diagonal_share={plan.stats.diagonal_share:.4f} "
+             if plan is not None else "") +
           f"conflicts={res.total_conflicts} proper={ok} "
           f"converged={res.converged} "
           f"comm/round={res.comm_bytes_per_round}B "

@@ -13,6 +13,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
+from repro.kernels.fused_round import DEFAULT_TILE
 
 
 def _mk_inputs(n, w, n_ghost, n_colors, seed, deg_max=50):
@@ -356,3 +357,228 @@ def test_backend_parity_single_device():
     pal = color_single_device(g, backend="pallas")
     assert (ref.colors == pal.colors).all()
     assert ref.rounds == pal.rounds
+
+
+# ---------------------------------------------------------------------------
+# Diagonal neighbor reads (kernels.diagonals): the blocks, and with them
+# colors, iterations, lose flags and conflict counts, equal the gather's.
+# ---------------------------------------------------------------------------
+
+def _hex_graph(spec, extra=0, seed=0):
+    """An x-major hex mesh, plus ``extra`` random edges: entries on no
+    diagonal, so the layout keeps a residual."""
+    from repro.graph.csr import build_graph
+    from repro.graph.generators import hex_mesh
+
+    g = hex_mesh(*spec)
+    if not extra:
+        return g
+    rng = np.random.default_rng(seed)
+    src = np.repeat(np.arange(g.n, dtype=np.int32), np.diff(g.offsets))
+    keep = src < g.targets
+    return build_graph(
+        np.concatenate([src[keep], rng.integers(0, g.n, extra)]),
+        np.concatenate([g.targets[keep], rng.integers(0, g.n, extra)]),
+        g.n, name=f"hex_plus_{extra}")
+
+
+def _hex_state(spec, parts, problem, extra=0):
+    """Stacked state of a (perturbed) hex mesh and its diagonal layout."""
+    from repro.core.distributed import build_device_state, neighbor_diagonals
+    from repro.graph.partition import partition_graph
+
+    pg = partition_graph(_hex_graph(spec, extra), parts,
+                         second_layer=problem != "d1")
+    st_ = build_device_state(pg, problem)
+    diag, share = neighbor_diagonals(st_, problem)
+    assert diag is not None
+    assert (diag.res_pos.shape[-1] > 0) == (share < 1.0) == (extra > 0)
+    return st_, diag
+
+
+def _hex_part(spec, parts, problem, extra=0, seed=0):
+    """One part's arrays (an inner one where there are several), random
+    colors and ghost colors, and the part's slice of the layout."""
+    st_, diag = _hex_state(spec, parts, problem, extra)
+    part = min(1, parts - 1)
+    s = {k: jnp.asarray(v[part]) for k, v in st_.items()}
+    rng = np.random.default_rng(seed)
+    n, g = s["is_boundary"].shape[0], s["ghost_real"].shape[0]
+    s["colors"] = jnp.asarray(rng.integers(0, 7, n).astype(np.int32))
+    s["ghost"] = jnp.asarray(rng.integers(0, 7, g).astype(np.int32))
+    return s, jax.tree_util.tree_map(lambda x: jnp.asarray(x[part]), diag)
+
+
+# (graph, parts, problem, extra edges).  One part of a hex mesh has every
+# entry on a diagonal, and four x-slabs their ghosts too (one or two
+# offsets a face); the extra random edges leave a residual.  No row count
+# is a multiple of the 128-lane tile (ragged tails).
+HEX_CASES = [((12, 10, 9), 1, "d1", 0), ((9, 8, 7), 1, "d2", 0),
+             ((9, 8, 7), 1, "pd2", 0), ((16, 12, 10), 4, "d1", 0),
+             ((16, 12, 10), 4, "d1", 40), ((16, 10, 9), 4, "d2", 3)]
+HEX_IDS = [f"{p}-{n}part" + ("-residual" if x else "")
+           for _, n, p, x in HEX_CASES]
+
+
+@pytest.mark.parametrize("spec,parts,problem,extra", HEX_CASES, ids=HEX_IDS)
+@pytest.mark.parametrize("tile", [128, 384])
+def test_read_neighbors_matches_gather(spec, parts, problem, extra, tile):
+    """The diagonal kernel's blocks == ``X[idx_t]``, two tables at once."""
+    from repro.kernels.diagonals import read_neighbors
+    from repro.kernels.fused_round import _lane_layout, neighbor_index
+
+    st_, diag = _hex_state(spec, parts, problem, extra)
+    idx = neighbor_index(st_["adj_cidx"], st_.get("two_hop_cidx"), problem)
+    n_tab = st_["deg_tab"].shape[-1]
+    rng = np.random.default_rng(tile)
+    for part in range(parts):
+        tab = jnp.asarray(rng.integers(0, 1 << 30, n_tab).astype(np.int32))
+        t, _, idx_t = _lane_layout(jnp.asarray(idx[part]), n_tab, tile)
+        d = jax.tree_util.tree_map(lambda x: jnp.asarray(x[part]), diag)
+        got = read_neighbors(idx_t, d, tab, tab[::-1], tile=t,
+                             interpret=True)
+        np.testing.assert_array_equal(np.asarray(got[0]),
+                                      np.asarray(tab[idx_t]))
+        np.testing.assert_array_equal(np.asarray(got[1]),
+                                      np.asarray(tab[::-1][idx_t]))
+
+
+@pytest.mark.parametrize("spec,parts,problem,extra", HEX_CASES, ids=HEX_IDS)
+def test_fused_round_diagonal_parity(spec, parts, problem, extra):
+    """Diagonal reads == the gather path == the decomposed oracle."""
+    s, diag = _hex_part(spec, parts, problem, extra, seed=parts)
+    th = s.get("two_hop_cidx")
+    args = (s["adj_cidx"], s["colors"], s["ghost"], s["deg_tab"],
+            s["gid_tab"], s["is_boundary"])
+    got = ops.fused_round(*args, two_hop_cidx=th, diag=diag,
+                          problem=problem, tile=128)
+    gather = ops.fused_round(*args, two_hop_cidx=th, problem=problem,
+                             tile=128)
+    want = ref.fused_round_ref(*args, two_hop_cidx=th,
+                               ext_adj_cidx=s.get("ext_adj_cidx"),
+                               problem=problem)
+    for name, g_, a_, w_ in zip(("colors", "lose_l", "lose_g", "conf",
+                                 "iters"), got, gather, want):
+        np.testing.assert_array_equal(np.asarray(g_), np.asarray(a_),
+                                      err_msg=name)
+        np.testing.assert_array_equal(np.asarray(g_), np.asarray(w_),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("case", [4, 5], ids=[HEX_IDS[4], HEX_IDS[5]])
+def test_fused_round_diagonal_pairs(case):
+    """Ghost updates (``pair_slots``) land before the diagonal reads."""
+    spec, parts, problem, extra = HEX_CASES[case]
+    s, diag = _hex_part(spec, parts, problem, extra, seed=9)
+    gh = s["ghost"].shape[0]
+    rng = np.random.default_rng(13)
+    slots = np.full(gh // 2, gh, np.int32)          # pad sentinel drops
+    slots[: gh // 4] = rng.permutation(gh)[: gh // 4]
+    pairs = dict(pair_slots=jnp.asarray(slots), pair_colors=jnp.asarray(
+        rng.integers(1, 7, slots.size).astype(np.int32)))
+    args = (s["adj_cidx"], s["colors"], s["ghost"], s["deg_tab"],
+            s["gid_tab"], s["is_boundary"])
+    th = s.get("two_hop_cidx")
+    got = ops.fused_round(*args, two_hop_cidx=th, diag=diag,
+                          problem=problem, tile=128, **pairs)
+    want = ops.fused_round(*args, two_hop_cidx=th, problem=problem,
+                           tile=128, **pairs)
+    assert int(got[3]) > 0                          # conflicts to resolve
+    for g_, w_ in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g_), np.asarray(w_))
+
+
+@pytest.mark.parametrize("spec,parts,problem,extra", HEX_CASES[:3],
+                         ids=HEX_IDS[:3])
+def test_speculate_diagonal_parity_masked(spec, parts, problem, extra):
+    """A masked request (a random third of the rows active, the rest
+    frozen at their colors): same table and iteration count."""
+    from repro.kernels.fused_round import neighbor_index, speculate
+
+    s, diag = _hex_part(spec, parts, problem, extra, seed=4)
+    idx = neighbor_index(s["adj_cidx"], s.get("two_hop_cidx"), problem)
+    n = s["colors"].shape[0]
+    active = jnp.asarray(np.random.default_rng(5).random(n) < 1 / 3)
+    tab = jnp.concatenate([jnp.where(active, 0, s["colors"]), s["ghost"],
+                           jnp.zeros((1,), jnp.int32)])
+    kw = dict(tile=128, max_iters=1024)
+    got = speculate(idx, tab, active, s["deg_tab"], s["gid_tab"], diag=diag,
+                    **kw)
+    want = speculate(idx, tab, active, s["deg_tab"], s["gid_tab"], **kw)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert int(got[1]) == int(want[1]) > 0
+
+
+def test_find_diagonals_sees_a_diagonal_in_step_with_the_mesh():
+    """hex:256,256,2 has 131,072 rows, more than the analysis samples; the
+    ``-1`` diagonal lies on odd rows only, so a sample of every other row
+    would miss it (a tenth of the entries)."""
+    from repro.core.distributed import build_device_state, neighbor_diagonals
+    from repro.graph.generators import hex_mesh
+    from repro.graph.partition import partition_graph
+
+    st_ = build_device_state(partition_graph(hex_mesh(256, 256, 2), 1), "d1")
+    diag, share = neighbor_diagonals(st_, "d1")
+    assert diag.offsets == (-512, -2, -1, 1, 2, 512)
+    assert share == 1.0 and diag.res_pos.shape == (1, 0)
+
+
+def _relabeled_hex(spec, seed):
+    from repro.graph.csr import build_graph
+    from repro.graph.generators import hex_mesh
+
+    g = hex_mesh(*spec)
+    perm = np.random.default_rng(seed).permutation(g.n).astype(np.int32)
+    src = np.repeat(np.arange(g.n, dtype=np.int32), np.diff(g.offsets))
+    return build_graph(perm[src], perm[g.targets], g.n, name="hex_relabeled")
+
+
+@pytest.mark.parametrize("graph", ["rmat", "er", "hex_relabeled", "hex"])
+def test_plan_reads_along_diagonals_only_where_observed(graph):
+    """The plan's counter says which path each graph took; both paths
+    color like the reference, full and masked."""
+    from repro.core.plan import build_plan
+    from repro.graph.generators import erdos_renyi, hex_mesh, rmat
+    from repro.graph.partition import partition_graph
+
+    g = {"rmat": lambda: rmat(8, 6, seed=2),
+         "er": lambda: erdos_renyi(300, 5, seed=3),
+         "hex_relabeled": lambda: _relabeled_hex((8, 7, 6), 4),
+         "hex": lambda: hex_mesh(8, 7, 6)}[graph]()
+    pg = partition_graph(g, 1)
+    kw = dict(problem="d1", engine="simulate", exchange="sparse_delta")
+    plan = build_plan(pg, backend="pallas_fused", **kw)
+    stats = plan.stats
+    if graph == "hex":
+        assert (stats.diagonals, stats.diagonal_share) == (6, 1.0)
+        assert "nbr_diag" in plan.state
+    else:
+        assert (stats.diagonals, stats.diagonal_share) == (0, 0.0)
+        assert "nbr_diag" not in plan.state
+    oracle = build_plan(pg, backend="reference", **kw)
+    assert (oracle.stats.diagonals, oracle.stats.diagonal_share) == (0, 0.0)
+    mask = np.random.default_rng(6).random(g.n) < 0.3
+    full = oracle.run()
+    for run_kw in ({}, dict(color_mask=mask, colors0=full.colors)):
+        a, b = plan.run(**run_kw), oracle.run(**run_kw)
+        assert (a.colors == b.colors).all()
+        assert (a.rounds, a.total_conflicts, a.spec_iters) == (
+            b.rounds, b.total_conflicts, b.spec_iters)
+
+
+@pytest.mark.parametrize("problem,least,share", [("d1", 6, 0.98),
+                                                 ("d2", 25, 0.95)])
+def test_plan_counts_ghost_diagonals_on_four_parts(problem, least, share):
+    """Four x-slabs: the owned diagonals plus the ghosts' offsets (d2's
+    ghosts of ghosts add more than the kernel takes); counted when the
+    plan is built, before any run."""
+    from repro.core.plan import build_plan
+    from repro.graph.generators import hex_mesh
+    from repro.graph.partition import partition_graph
+
+    pg = partition_graph(hex_mesh(16, 12, 10), 4,
+                         second_layer=problem != "d1")
+    plan = build_plan(pg, problem=problem, backend="pallas_fused",
+                      engine="simulate", exchange="sparse_delta")
+    assert plan.stats.diagonals >= least
+    assert plan.stats.diagonal_share >= share

@@ -8,6 +8,8 @@ does not fit the chip's memory.  Shapes are handed in as
 inside a fixture, never at import: only one process may load the TPU
 library, and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -77,6 +79,34 @@ def test_fused_round_compiles(spec, problem):
     assert "tpu_custom_call" in txt
 
 
+# The diagonals of an x-major hex mesh's index (hex:256³ slabs for d1,
+# hex:128³ for d2: strides 1, nz, ny·nz), and a residual of one ghost
+# face per side.
+HEX_OFFSETS = {
+    "d1": (-65536, -256, -1, 1, 256, 65536),
+    "d2": tuple(sorted({a + b for a in (-16384, -128, -1, 0, 1, 128, 16384)
+                        for b in (-16384, -128, -1, 0, 1, 128, 16384)})),
+}
+
+
+@pytest.mark.parametrize("problem", ["d1", "d2"])
+def test_fused_round_compiles_on_diagonals(spec, problem):
+    """The diagonal reads at real shard widths: no gather but the
+    residual's, and the kernels still there."""
+    from repro.kernels.diagonals import Diagonals
+
+    dims = D1 if problem == "d1" else D2
+    h2 = dims["w"] ** 2 if problem == "d2" else None
+    args, two_hop = _round_args(spec, **dims, h2=h2)
+    face = 65_536 if problem == "d1" else 16_384
+    diag = Diagonals(HEX_OFFSETS[problem], spec((2 * face,)),
+                     spec((2 * face,)))
+    assert len(diag.offsets) == (6 if problem == "d1" else 25)
+    txt = compiled_text(ops.fused_round, *args, two_hop_cidx=two_hop,
+                        diag=diag, problem=problem, interpret=False)
+    assert "tpu_custom_call" in txt
+
+
 def test_fused_round_compiles_pd2_bip_shard(spec):
     """pd2 on one of 8 parts of a ``bip:`` Jacobian graph (real shard)."""
     from repro.core.distributed import build_device_state
@@ -141,10 +171,36 @@ def test_simulate_plan_loop_compiles(spec):
     assert "tpu_custom_call" in txt
 
 
+def test_simulate_plan_loop_compiles_on_diagonals(spec):
+    """The ``hex128-d1`` benchmark configuration's loop program, one part
+    on one chip: its neighbor blocks are all read along the mesh's six
+    diagonals, so no gather is left."""
+    import numpy as np
+
+    from repro.core.backend import PallasFusedBackend
+    from repro.core.plan import build_plan
+    from repro.graph.partition import partition_graph
+    from repro.launch.color import make_graph
+
+    pg = partition_graph(make_graph("hex:128,128,128"), 1)
+    plan = build_plan(pg, problem="d1", engine="simulate",
+                      backend=PallasFusedBackend(interpret=False),
+                      exchange="sparse_delta")
+    assert (plan.stats.diagonals, plan.stats.diagonal_share) == (6, 1.0)
+    shapes = lambda x: spec(np.shape(x), x.dtype)  # noqa: E731
+    st = jax.tree_util.tree_map(shapes, plan.state)
+    inputs = [shapes(np.asarray(x)) for x in plan.request_inputs()]
+    txt = compiled_text(jax.jit(plan.raw_fn, donate_argnums=(1,)), st,
+                        *inputs)
+    assert "tpu_custom_call" in txt
+    assert not re.search(r"= \S+ gather\(", txt)
+
+
 def test_shard_map_plan_loop_compiles_on_four_chips(topo):
     """The four-chip cell's loop program (``shard_map`` over a 2x2 mesh,
     ``sparse_delta``'s ``ppermute`` phases, the per-part iteration count
-    as a sharded output), at a small mesh."""
+    as a sharded output, neighbor reads along the diagonals), at a small
+    mesh."""
     import copy
 
     import numpy as np
@@ -153,6 +209,7 @@ def test_shard_map_plan_loop_compiles_on_four_chips(topo):
 
     from repro.core.backend import PallasFusedBackend
     from repro.core.exchange import get_exchange
+    from repro.core.distributed import neighbor_diagonals
     from repro.core.plan import (PlanStats, _build_shard_map_fn,
                                  cached_device_state)
     from repro.graph.partition import partition_graph
@@ -162,6 +219,9 @@ def test_shard_map_plan_loop_compiles_on_four_chips(topo):
     strategy = copy.copy(get_exchange("sparse_delta"))
     st_np = dict(cached_device_state(pg, "d1"))
     active0 = st_np.pop("active0")
+    # The diagonal reads, with the far ghosts' residual sharded per part.
+    st_np["nbr_diag"], _ = neighbor_diagonals(st_np, "d1")
+    assert st_np["nbr_diag"].res_pos.shape[0] == 4
     st_np.update(strategy.prepare(pg, st_np))
     mesh = Mesh(np.array(topo.devices), ("p",), axis_types=(AxisType.Auto,))
     _, fn = _build_shard_map_fn(
@@ -176,7 +236,7 @@ def test_shard_map_plan_loop_compiles_on_four_chips(topo):
 
     colors0 = np.zeros((4, pg.n_local), np.int32)
     ghost0 = np.zeros(pg.ghost_gid.shape, np.int32)
-    compiled = fn.lower({k: shape(v) for k, v in st_np.items()},
+    compiled = fn.lower(jax.tree_util.tree_map(shape, st_np),
                         shape(colors0), shape(ghost0), shape(active0),
                         shape(np.int32(0), rep)).compile()
     txt = compiled.as_text()
