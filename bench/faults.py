@@ -7,7 +7,7 @@ own runs plant none).
 * ``unchanged``   — the step returns the state it was given (``colors0``);
 * ``half``        — every second active cell is left out of the request;
 * ``altered``     — one answer is altered where it is produced: an active
-  cell takes a neighbor's color;
+  cell takes its first neighbor's color;
 * ``no_exchange`` — the exchange between parts delivers nothing: every
   ghost color a part reads stays 0.
 """
@@ -74,9 +74,10 @@ def wrap(fault: str | None, call, ref):
             mask[np.flatnonzero(mask)[1::2]] = False
             return call(Request(mask, req.colors0))
         ans = call(req)                                    # "altered"
-        v = int(np.flatnonzero(active(req) & (ref.table[:, 0] >= 0))[0])
+        csr = ref.csr
+        v = int(np.flatnonzero(active(req) & (csr.sizes() > 0))[0])
         ans.colors = ans.colors.copy()
-        ans.colors[v] = ans.colors[ref.table[v, 0]]
+        ans.colors[v] = ans.colors[csr.indices[csr.indptr[v]]]
         return ans
 
     return faulty

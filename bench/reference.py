@@ -16,26 +16,37 @@ are compared, each with its limit.  :func:`control` is this module's own
 coloring with its conflict resolution left out: every active cell takes
 the lowest color its neighbors do not hold, all at once.  It breaks the
 "proper" guarantee and is what the checks must catch.
+
+Neighborhoods are CSR (:class:`bench.references.CSR`), so a degree-skewed
+graph costs what its edges cost, not rows × largest degree.
 """
 from __future__ import annotations
 
 import numpy as np
 
 from bench import references
-from bench.references import PAD
+from bench.references import CSR
 
 
-def neighbor_table(g) -> np.ndarray:
-    """``(n, max degree)`` int32 neighbor ids, padded with ``PAD``."""
-    s = np.concatenate([g.src, g.dst]).astype(np.int64)
-    d = np.concatenate([g.dst, g.src]).astype(np.int32)
+def neighbor_csr(g) -> CSR:
+    """Each row's distinct neighbors, self-loops left out.
+
+    A row lists its neighbors in the order the edge list first names them:
+    its edges as ``src``, then its edges as ``dst``.
+    """
+    src, dst = np.asarray(g.src), np.asarray(g.dst)
+    key = np.minimum(src, dst).astype(np.int64) * g.n + np.maximum(src, dst)
+    key[src == dst] = -1
+    _, first = np.unique(key, return_index=True)
+    first = np.sort(first[key[first] >= 0])
+    src, dst = src[first], dst[first]
+    del key
+    s = np.concatenate([src, dst])
     order = np.argsort(s, kind="stable")
-    s, d = s[order], d[order]
-    deg = np.bincount(s, minlength=g.n)
-    start = np.cumsum(deg) - deg
-    tab = np.full((g.n, int(deg.max(initial=1))), PAD, np.int32)
-    tab[s, np.arange(s.size) - start[s]] = d
-    return tab
+    indptr = np.zeros(g.n + 1, np.int64)
+    np.cumsum(np.bincount(s, minlength=g.n), out=indptr[1:])
+    del s
+    return CSR(indptr, np.concatenate([dst, src]).astype(np.int32)[order])
 
 
 class Reference:
@@ -44,25 +55,25 @@ class Reference:
     def __init__(self, problem: str, g):
         self.problem, self.g = problem, g
         self._rules = references.load(problem)
-        self._tab = self._hood = None
+        self._csr = self._hood = None
 
     @property
-    def table(self) -> np.ndarray:
-        """Distance-1 neighbor ids, ``PAD``-ed."""
-        if self._tab is None:
-            self._tab = neighbor_table(self.g)
-        return self._tab
+    def csr(self) -> CSR:
+        """Distance-1 neighbors."""
+        if self._csr is None:
+            self._csr = neighbor_csr(self.g)
+        return self._csr
 
     @property
-    def hood(self) -> np.ndarray:
-        """The cells each row must differ from, ``PAD``-ed."""
+    def hood(self) -> CSR:
+        """The distinct cells each row must differ from."""
         if self._hood is None:
             self._hood = self._rules.hood(self)
         return self._hood
 
     def hood_sizes(self) -> np.ndarray:
         """Distinct cells each row must differ from."""
-        return (self.hood >= 0).sum(axis=1)
+        return self.hood.sizes()
 
     def max_color_limit(self) -> int:
         return int(self.hood_sizes().max(initial=0)) + 1
@@ -91,17 +102,27 @@ class Reference:
                 "max_color": self.max_color_limit()}
 
     def control(self, mask=None, colors0=None) -> np.ndarray:
-        """First fit for every active cell at once, no conflict resolution."""
+        """First fit for every active cell at once, no conflict resolution.
+
+        One sort of the active rows' distinct (row, held color) pairs: in a
+        row's sorted colors ``1, 2, ..., k`` are held exactly where the j-th
+        color is ``j``, so its first fit is one more than their count.
+        """
         n = self.g.n
         c = (np.zeros(n, np.int32) if colors0 is None
              else np.array(colors0, np.int32))
         active = np.ones(n, bool) if mask is None else np.asarray(mask)
         c[active] = 0
-        hood = self.hood
-        held = np.where(hood >= 0, c[np.maximum(hood, 0)], 0)
-        taken = np.zeros((n, max(int(held.max(initial=0)), 0) + 2), bool)
-        taken[np.arange(n)[:, None], held] = True
-        first = np.argmin(taken[:, 1:], axis=1) + 1
+        hood, sizes = self.hood, self.hood.sizes()
+        row = np.repeat(np.arange(n, dtype=np.int32),
+                        np.where(active, sizes, 0))
+        held = c[hood.indices[np.repeat(active, sizes)]]
+        row, held = row[held > 0], held[held > 0]
+        width = int(held.max(initial=0)) + 1
+        key = np.unique(row.astype(np.int64) * width + held)
+        row = key // width
+        rank = np.arange(key.size) - np.searchsorted(row, row) + 1
+        first = 1 + np.bincount(row[key % width == rank], minlength=n)
         return np.where(active, first, c).astype(np.int32)
 
 

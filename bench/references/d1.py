@@ -3,9 +3,11 @@ from __future__ import annotations
 
 import numpy as np
 
+from bench.references import CSR
 
-def hood(ref) -> np.ndarray:
-    return ref.table
+
+def hood(ref) -> CSR:
+    return ref.csr
 
 
 def improper(ref, colors: np.ndarray) -> int:

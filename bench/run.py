@@ -9,9 +9,13 @@ One process: set-up (graph, partition, plan, compile or cache load, a
 warm-up request of the cell's own kind), then a closed-loop window of
 ``--seconds`` through ``ColoringService.submit`` — the next request goes
 when the previous one has returned, and the one in flight at the end
-finishes and counts — then the reference's checks of every answer the
-window returned.  ``--trace 1`` records the window with the JAX profiler
-and reports the per-layer metrics; ``--trace 0`` the end-to-end ones.
+finishes and counts — then the reference's checks of a sample of the
+window's answers, ``CHECKED`` of them drawn from the seed (all of them in
+a window that returns fewer).  The window keeps only the sample: every
+other answer is dropped when the next request is built, so the host
+reuses its memory instead of touching fresh pages each request.
+``--trace 1`` records the window with the JAX profiler and reports the
+per-layer metrics; ``--trace 0`` the end-to-end ones.
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -50,6 +54,12 @@ from bench.reference import Reference, passes  # noqa: E402
 from bench.requests import Request, Requests  # noqa: E402
 
 
+# Answers of a window that the reference checks: a uniform sample, drawn
+# from the seed, of fixed size (a reservoir), so neither the memory the
+# window holds nor the time the checks take grows with its requests.
+CHECKED = 32
+
+
 class NoChip(RuntimeError):
     """JAX finds no TPU, or fewer chips than the cell asks for."""
 
@@ -73,7 +83,8 @@ class Run:
     memory_peak_bytes: int | None
     device: dict                # platform, kind and count as JAX reports
     checks: dict                # compared number -> {"value", "limit"}
-    failed: int                 # requests whose answer failed a check
+    checked: int                # requests whose answer was checked
+    failed: int                 # checked answers that failed a check
     compiles: int               # compile events inside the window
     trace: tracing.Summary | None = None
 
@@ -112,6 +123,27 @@ class CompileEvents:
     def _seen(self, event, duration, **_):
         if self.on and event.startswith("/jax/core/compile/"):
             self.n += 1
+
+
+class Sample:
+    """A uniform sample of ``size`` of the items offered, the choice drawn
+    from ``seed`` (Algorithm R): the i-th item takes a place with chance
+    size / (i + 1), in place of a uniform one."""
+
+    def __init__(self, size: int, seed: int):
+        self.size, self.seen, self.kept = size, 0, {}
+        self.rng = np.random.default_rng([seed, 1])
+
+    def offer(self, i: int, item):
+        slot = self.seen if self.seen < self.size else int(
+            self.rng.integers(0, self.seen + 1))
+        self.seen += 1
+        if slot < self.size:
+            self.kept[slot] = (i, item)
+
+    def items(self) -> list:
+        """The kept ``(i, item)`` pairs, in the order offered."""
+        return sorted(self.kept.values(), key=lambda x: x[0])
 
 
 class Collections:
@@ -189,7 +221,7 @@ def run_cell(cell: manifest.Cell, *, seed: int, seconds: float, trace: bool,
             prev = call(Request(None, None)).colors if gen.warm_start else None
             prev = call(gen.next(prev)).colors
         compiles, collections = CompileEvents(), Collections()
-        window = []
+        window, sample = [], Sample(CHECKED, seed)
         with profiled(trace) as recorded:
             compiles.on = collections.on = True
             t0 = time.perf_counter()
@@ -201,11 +233,15 @@ def run_cell(cell: manifest.Cell, *, seed: int, seconds: float, trace: bool,
                             collections.ns)
                     with TraceAnnotation("request"):
                         ans = call(req)
-                    window.append((req, ans, (
-                        time.perf_counter() - host[0],
-                        time.process_time() - host[1],
-                        (collections.ns - host[2]) / 1e9)))
+                    window.append(dict(
+                        seconds=time.perf_counter() - host[0],
+                        cpu_s=time.process_time() - host[1],
+                        gc_s=(collections.ns - host[2]) / 1e9,
+                        rounds=ans.rounds, comm_bytes=ans.comm_bytes,
+                        mask=req.mask))
+                    sample.offer(len(window) - 1, (req, ans))
                     prev = ans.colors
+                    del req, ans
                     if time.perf_counter() - t0 >= seconds:
                         break
             t1 = time.perf_counter()
@@ -222,20 +258,20 @@ def run_cell(cell: manifest.Cell, *, seed: int, seconds: float, trace: bool,
     limits = ref.limits()
     hood = ref.hood_sizes()
     worst = {k: 0 for k in limits}
-    requests, failed = [], 0
-    for req, ans, (took, cpu, in_gc) in window:
+    failed = 0
+    for r in window:
+        r["least_bytes"] = roofline.least_bytes(hood, r.pop("mask"))
+    for i, (req, ans) in sample.items():
         nums = ref.check(ans.colors, req.mask, req.colors0)
         failed += not passes(nums, limits)
         worst = {k: max(worst[k], nums[k]) for k in limits}
-        requests.append(dict(
-            seconds=took, cpu_s=cpu, gc_s=in_gc,
-            rounds=ans.rounds, comm_bytes=ans.comm_bytes,
-            colors=int(np.count_nonzero(np.bincount(ans.colors)[1:])),
-            least_bytes=roofline.least_bytes(hood, req.mask)))
+        window[i]["colors"] = int(
+            np.count_nonzero(np.bincount(ans.colors)[1:]))
     return Run(
         chips=cell.chips, peaks=peaks, setup_s=t0 - T_START,
-        window_s=t1 - t0, requests=requests, memory_peak_bytes=memory,
-        device=device, failed=failed, compiles=compiles.n,
+        window_s=t1 - t0, requests=window, memory_peak_bytes=memory,
+        device=device, checked=len(sample.kept), failed=failed,
+        compiles=compiles.n,
         checks={k: {"value": worst[k], "limit": limits[k]} for k in limits},
         trace=tracing.summarize(recorded["events"]) if trace else None,
         **setup)
@@ -249,7 +285,7 @@ def result_line(cell: manifest.Cell, run: Run) -> dict:
         if value is not None:
             metrics[m.name] = {"value": value, "unit": m.unit}
     device = dict(run.device, memory_peak_bytes=run.memory_peak_bytes)
-    out = {"correct": run.failed == 0 and bool(run.requests),
+    out = {"correct": run.failed == 0 and run.checked > 0,
            "attempted": len(run.requests), "failed": run.failed,
            "metrics": metrics, "device": device}
     if run.trace is not None:
@@ -264,7 +300,7 @@ def result_line(cell: manifest.Cell, run: Run) -> dict:
     slowest = max(run.requests, key=lambda r: r["seconds"])
     out["window"] = {
         "requests": len(run.requests), "seconds": run.window_s,
-        "compiles": run.compiles,
+        "checked": run.checked, "compiles": run.compiles,
         "request_ms": [took[0], took[len(took) // 2], took[-1]],
         "gc_ms": sum(r["gc_s"] for r in run.requests) * 1e3,
         "slowest": {"ms": slowest["seconds"] * 1e3,

@@ -117,3 +117,142 @@ def test_an_unmasked_mix_colors_every_cell_from_zero():
     gen = Requests({"mask": "none", "warm_start": False}, mesh(4, 4, 4), 1)
     req = gen.next(parity(mesh(4, 4, 4)))
     assert req.mask is None and req.colors0 is None
+
+
+# --- CSR neighborhoods against Python sets --------------------------------
+
+from collections import Counter  # noqa: E402
+
+from bench.graphs import GraphData  # noqa: E402
+from bench.graphs import kron  # noqa: E402
+from bench.reference import neighbor_csr  # noqa: E402
+from bench.references import d2 as d2_rules  # noqa: E402
+
+
+def kron_graph(scale, seed=3):
+    return kron.make({"kind": "kron", "scale": scale, "edge_factor": 16,
+                      "initiator": [0.57, 0.19, 0.19], "seed": seed})
+
+
+def with_isolated():
+    """40 vertices, 12 of them isolated; one edge named twice."""
+    rng = np.random.default_rng(11)
+    src = rng.integers(0, 28, 60)
+    dst = (src + rng.integers(1, 28, 60)) % 28
+    src, dst = np.r_[src, dst[0]], np.r_[dst, src[0]]
+    return GraphData(n=40, src=src.astype(np.int32), dst=dst.astype(np.int32))
+
+
+GRAPHS = {"hex": lambda: mesh(5, 4, 3), "path": lambda: mesh(9, 1, 1),
+          "isolated": with_isolated, "kron10": lambda: kron_graph(10)}
+
+
+def set_hoods(g, problem):
+    """Each row's cells by Python sets of neighbors."""
+    nb = [set() for _ in range(g.n)]
+    for u, v in zip(g.src.tolist(), g.dst.tolist()):
+        if u != v:
+            nb[u].add(v)
+            nb[v].add(u)
+    if problem == "d1":
+        return nb, nb
+    return nb, [nb[v].union(*(nb[u] for u in nb[v])) - {v}
+                for v in range(g.n)]
+
+
+def set_improper(g, problem, colors, nb):
+    if problem == "d1":
+        return sum(colors[u] == colors[v] > 0
+                   for u, v in zip(g.src.tolist(), g.dst.tolist()))
+    total = 0
+    for v in range(g.n):
+        held = Counter(colors[u] for u in [v, *nb[v]] if colors[u] > 0)
+        total += sum(m - 1 for m in held.values())
+    return total
+
+
+def first_fit(hoods, colors0, active):
+    c = np.where(active, 0, colors0)
+    out = c.copy()
+    for v in np.flatnonzero(active):
+        held = {c[u] for u in hoods[v]}
+        out[v] = next(k for k in range(1, len(held) + 2) if k not in held)
+    return out
+
+
+def row(csr, v):
+    return csr.indices[csr.indptr[v]:csr.indptr[v + 1]].tolist()
+
+
+@pytest.mark.parametrize("problem", ["d1", "d2"])
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
+def test_csr_reference_equals_python_sets(graph, problem):
+    g = GRAPHS[graph]()
+    ref = Reference(problem, g)
+    nb, hoods = set_hoods(g, problem)
+    hood = ref.hood
+    assert hood.indptr.dtype == np.int64 and hood.indices.dtype == np.int32
+    for v in range(g.n):
+        cells = row(hood, v)
+        assert len(cells) == len(set(cells)) and set(cells) == hoods[v], v
+    sizes = np.array([len(h) for h in hoods])
+    assert np.array_equal(ref.hood_sizes(), sizes)
+    assert ref.limits()["max_color"] == sizes.max() + 1
+    rng = np.random.default_rng(5)
+    for top in (1, 3, 8, 40):
+        colors = rng.integers(0, top + 1, g.n).astype(np.int32)
+        assert ref.improper(colors) == set_improper(g, problem, colors, nb)
+    greedy = np.zeros(g.n, np.int32)      # a sequential first fit is proper
+    for v in range(g.n):
+        held = {greedy[u] for u in hoods[v]}
+        greedy[v] = next(k for k in range(1, g.n + 2) if k not in held)
+    assert ref.improper(greedy) == 0 == set_improper(g, problem, greedy, nb)
+    active = rng.random(g.n) < 0.4
+    assert np.array_equal(ref.control(active, greedy),
+                          first_fit(hoods, greedy, active))
+
+
+def test_control_on_a_kron_graph_is_first_fit_and_improper():
+    g = kron_graph(12)
+    ref = Reference("d1", g)
+    _, hoods = set_hoods(g, "d1")
+    full = ref.control()
+    assert np.array_equal(full, first_fit(hoods, np.zeros(g.n, np.int32),
+                                          np.ones(g.n, bool)))
+    assert ref.check(full)["improper"] > 0
+    rng = np.random.default_rng(9)
+    colors0 = rng.integers(1, 30, g.n).astype(np.int32)
+    active = rng.random(g.n) < 0.3
+    colors0[active] = 0
+    part = ref.control(active, colors0)
+    assert np.array_equal(part, first_fit(hoods, colors0, active))
+    nums = ref.check(part, active, colors0)
+    assert nums["improper"] > 0 and nums["frozen_changed"] == 0
+
+
+def test_neighbor_csr_drops_self_loops_and_repeats_in_edge_order():
+    g = GraphData(n=6, src=np.array([2, 0, 2, 3, 1, 4], np.int32),
+                  dst=np.array([0, 1, 2, 2, 0, 3], np.int32))
+    csr = neighbor_csr(g)
+    assert [row(csr, v) for v in range(6)] == [[1, 2], [0], [0, 3], [2, 4],
+                                               [3], []]
+
+
+def test_d2_refuses_a_graph_past_its_entry_budget(monkeypatch):
+    g = kron_graph(10)
+    deg = Reference("d1", g).hood_sizes()
+    estimate = int(deg @ deg)
+    monkeypatch.setattr(d2_rules, "ENTRY_BUDGET", estimate - 1)
+    with pytest.raises(ValueError, match=f"{estimate:,}"):
+        Reference("d2", g).limits()
+    monkeypatch.setattr(d2_rules, "ENTRY_BUDGET", estimate)
+    assert Reference("d2", g).limits()["max_color"] > 0
+
+
+def test_d2_blocks_give_the_same_hoods_as_one_block(monkeypatch):
+    g = kron_graph(10)
+    whole = Reference("d2", g).hood
+    monkeypatch.setattr(d2_rules, "BLOCK", 1000)
+    blocks = Reference("d2", g).hood
+    assert np.array_equal(whole.indptr, blocks.indptr)
+    assert np.array_equal(whole.indices, blocks.indices)

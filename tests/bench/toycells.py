@@ -20,20 +20,27 @@ from bench import manifest  # noqa: E402
 TOY_EXTENTS = {"hex128-d1": [8, 8, 8], "hex64-d2": [6, 6, 6],
                "hex-d1-4chip": [16, 8, 4]}
 
-
-# Cells whose files are in place but which BENCHMARK.json does not list
-# yet: the four-chip cell was not proved on the chip.
-UNLISTED = {"hex-d1-4chip-full": ("hex-d1-4chip", "full", 4)}
+# Toy cells that BENCHMARK.json does not list: (configuration, traffic,
+# chips, graph).  ``kron10-d1-full`` is ``hex128-d1`` with a Graph500
+# Kronecker graph of scale 10 in place of its mesh: the skewed degrees and
+# scattered ids of the gather path, on the program's CPU path.
+KRON10 = {"kind": "kron", "scale": 10, "edge_factor": 16,
+          "initiator": [0.57, 0.19, 0.19], "seed": 2**33 + 9}
+UNLISTED = {"kron10-d1-full": ("hex128-d1", "full", 1, KRON10)}
 
 
 def toy_cell(workload: str) -> manifest.Cell:
+    graph = None
     if workload in UNLISTED:
-        cell = manifest.cell(workload, *UNLISTED[workload],
-                             manifest.load_manifest())
+        *names, graph = UNLISTED[workload]
+        cell = manifest.cell(workload, *names, manifest.load_manifest())
     else:
         cell = manifest.resolve(workload)
     cfg = json.loads(json.dumps(cell.config))
-    cfg["graph"]["extents"] = TOY_EXTENTS[cfg["name"]]
+    if graph is None:
+        cfg["graph"]["extents"] = TOY_EXTENTS[cfg["name"]]
+    else:
+        cfg["graph"] = dict(graph)
     return dataclasses.replace(cell, config=cfg)
 
 
